@@ -313,7 +313,9 @@ def mean_bit_identity(tiny: bool) -> Dict[str, bool]:
     rounds_n = 2 if tiny else 3
     out = {eng: mean_identity_once(eng, rounds_n)[0]
            for eng in ("vmapped", "bucketed", "fl")}
-    env = dict(os.environ,
+    # the fabricated devices are CPU devices: the child must not reach
+    # for an accelerator this process already holds
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=6",
                PYTHONPATH=os.pathsep.join(
                    [os.path.join(_ROOT, "src"), _ROOT]

@@ -8,12 +8,14 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 # docs-consistency gate: every CLI flag documented in README.md must
-# exist in the sim/fed_train/benchmarks argparse definitions and vice
-# versa — new flags can't ship undocumented, docs can't rot silently
+# exist in the sim/fed_train/benchmarks/chip_smoke argparse definitions
+# and vice versa — new flags can't ship undocumented, docs can't rot
+# silently
 PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python - <<'PY'
 import re
 
 from benchmarks.run import build_parser as bench_parser
+from chip_smoke import build_parser as chip_parser
 from repro.launch.fed_train import build_parser as fed_parser
 from repro.launch.sim import build_parser as sim_parser
 
@@ -26,7 +28,8 @@ def flags(parser):
     return out
 
 
-in_code = flags(sim_parser()) | flags(fed_parser()) | flags(bench_parser())
+in_code = (flags(sim_parser()) | flags(fed_parser()) | flags(bench_parser())
+           | flags(chip_parser()))
 with open("README.md") as f:
     readme = f.read()
 # long flags only; the lookahead rejects tokens that continue with '_'

@@ -1,83 +1,24 @@
-"""jax version compatibility — the mesh / shard_map API family.
+"""Thin helpers over the installed jax's mesh and compile APIs.
 
-The codebase targets the modern spelling (``jax.shard_map`` with
-``axis_names``/``check_vma``, ``jax.set_mesh``, ``jax.make_mesh(...,
-axis_types=...)``, jax >= 0.6).  Older runtimes (0.4.x, the pinned CPU
-toolchain in some containers) ship the same machinery under
-``jax.experimental.shard_map`` with the complementary ``auto`` set,
-``check_rep``, and mesh-as-context-manager.  Everything routes through
-here so the rest of the repo writes ONE spelling.
-
-Mapping notes:
-* ``axis_names`` (axes the body handles manually) is the complement of
-  the old ``auto`` set (axes left to GSPMD).
-* ``check_vma`` (varying-mesh-axes check) renamed from ``check_rep``.
-* ``set_mesh(mesh)`` falls back to entering the ``Mesh`` context, which
-  is what pre-0.6 code used for ambient-mesh resolution.
+``jax.make_mesh`` builds ``Explicit`` axes by default; the repo's
+placement rules (``sharding.fleet``, ``sharding.rules``) rely on GSPMD
+propagating shardings through jitted steps, so every mesh here is built
+with all axes in ``Auto`` mode.
 """
 from __future__ import annotations
 
-from typing import Optional, Set
-
 import jax
-
-_HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_SET_MESH = hasattr(jax, "set_mesh")
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
-# Partial-manual shard_map (some mesh axes manual, the rest left to GSPMD)
-# CHECK-fails inside XLA's SPMD partitioner on 0.4.x runtimes
-# ("target.IsManualSubgroup() == sharding().IsManualSubgroup()") — the
-# expert-parallel MoE dispatch and flash-decode need it.  Fully-manual
-# shard_map (every mesh axis in axis_names) works on both runtimes.
-PARTIAL_AUTO_SHARD_MAP = _HAS_NEW_SHARD_MAP
 
 
 def make_mesh(axis_shapes, axis_names, *, devices=None):
-    """``jax.make_mesh`` with every axis in Auto (GSPMD) mode where the
-    runtime distinguishes axis types; plain mesh otherwise."""
+    """``jax.make_mesh`` with every axis in Auto (GSPMD) mode."""
     kwargs = {} if devices is None else {"devices": devices}
-    if _HAS_AXIS_TYPE:
-        kwargs["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-
-
-def set_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh."""
-    if _HAS_SET_MESH:
-        return jax.set_mesh(mesh)
-    return mesh                      # Mesh is itself a context manager
-
-
-def tpu_compiler_params(**kwargs):
-    """``pltpu.CompilerParams`` (renamed from ``TPUCompilerParams``) on
-    either runtime."""
-    import jax.experimental.pallas.tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    return jax.make_mesh(
+        axis_shapes, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names), **kwargs)
 
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on either runtime
-    (older jax returns a one-element list of per-device dicts)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
-
-
-def shard_map(f, *, mesh, in_specs, out_specs,
-              axis_names: Optional[Set[str]] = None,
-              check_vma: bool = False):
-    """Modern-signature shard_map on either runtime."""
-    if _HAS_NEW_SHARD_MAP:
-        kwargs = {"check_vma": check_vma}
-        if axis_names is not None:
-            kwargs["axis_names"] = axis_names
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _old
-    manual = set(mesh.axis_names) if axis_names is None else set(axis_names)
-    auto = frozenset(set(mesh.axis_names) - manual)
-    return _old(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                check_rep=check_vma, auto=auto)
+    """``compiled.cost_analysis()`` as a dict (empty when the backend
+    reports none)."""
+    return dict(compiled.cost_analysis() or {})

@@ -80,8 +80,15 @@ def replicate(params: Dict, n: int, sharding=None) -> Dict:
 
 
 def make_fed_step(loss_fn: LossFn, plan: Dict, num_layers: int,
-                  fed_cfg: FedPairingConfig):
+                  fed_cfg: FedPairingConfig, tied_head: bool = False):
     """Build the jitted per-batch FedPairing step.
+
+    ``tied_head``: the model ties its output head to ``params["embed"]``
+    (``ArchConfig.tie_embeddings``).  Flow i embeds its tokens with its own
+    matrix, but its head runs on the partner — the top owner — so the
+    loss sees the partner's matrix as ``tied_head`` and that gradient
+    returns to the partner's ``embed``, as in the bucketed and dist
+    engines.
 
     Returns ``step(client_params, batches, partner, lengths, agg_w)`` where
     * client_params — pytree stacked over N clients (donated unless
@@ -115,7 +122,12 @@ def make_fed_step(loss_fn: LossFn, plan: Dict, num_layers: int,
             return a * m + a[partner] * (1.0 - m)
 
         mixed = jax.tree_util.tree_map(mix, client_params, plan)
+        if tied_head:
+            mixed = dict(mixed, tied_head=client_params["embed"][partner])
         losses, g_mix = jax.vmap(jax.value_and_grad(loss_fn))(mixed, batches)
+        if tied_head:
+            g_mix = dict(g_mix)
+            g_head = g_mix.pop("tied_head")
 
         if fed_cfg.aggregation == "paper":
             a_own, a_in = agg_w, agg_w[partner]
@@ -141,6 +153,11 @@ def make_fed_step(loss_fn: LossFn, plan: Dict, num_layers: int,
             return p - fed_cfg.lr * u
 
         new_params = jax.tree_util.tree_map(apply, client_params, g_mix, plan)
+        if tied_head:
+            # the partner flow's head gradient on my matrix: a "top" term
+            b = (n,) + (1,) * (g_head.ndim - 1)
+            new_params["embed"] = new_params["embed"] - fed_cfg.lr * (
+                a_in.reshape(b) * g_head[partner])
         return new_params, {"loss": losses}
 
     return step

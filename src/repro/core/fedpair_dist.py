@@ -46,7 +46,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.core import fedbucket
 from repro.models import common, transformer
@@ -160,7 +159,7 @@ def make_dist_fed_step(cfg: ArchConfig, mesh, perm_pairs: Sequence[Tuple[int, in
     client_spec = P(axes)
 
     def total_loss(client_params, batch, masks_b, masks_perm, a_perm):
-        shard_fn = compat.shard_map(
+        shard_fn = jax.shard_map(
             flow_loss, mesh=mesh,
             in_specs=(jax.tree_util.tree_map(lambda _: client_spec,
                                              client_params),

@@ -286,8 +286,10 @@ class _VmappedEngine:
         fed_cfg = fedpair.FedPairingConfig(
             lr=rc.lr / n, overlap_boost=rc.overlap_boost,
             aggregation=rc.aggregation, donate=rc.donate)
-        self._step = fedpair.make_fed_step(loss_fn, plan, cfg.num_layers,
-                                           fed_cfg)
+        self._step = fedpair.make_fed_step(
+            loss_fn, plan, cfg.num_layers, fed_cfg,
+            tied_head=getattr(cfg, "tie_embeddings", False)
+            and "embed" in gparams)
         self.cached_steps = 1
 
     def step(self, params, batch, plan: RoundPlan, agg_w):
@@ -374,7 +376,7 @@ class _DistEngine:
     def step(self, params, batch, plan: RoundPlan, agg_w):
         key = _plan_key(plan, agg_w)
         built = self._cache.get(key)
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             if built is None:
                 dcfg = self._dist.FedDistConfig(
                     lr=self._rc.lr, overlap_boost=self._rc.overlap_boost,
@@ -393,7 +395,7 @@ class _DistEngine:
         key = _plan_key(plan, agg_w)
         if key in self._cache:
             return False
-        with compat.set_mesh(self.mesh):
+        with jax.set_mesh(self.mesh):
             dcfg = self._dist.FedDistConfig(
                 lr=self._rc.lr, overlap_boost=self._rc.overlap_boost,
                 split_ranges=plan.phase_envelope(),
@@ -431,8 +433,9 @@ class RoundDriver:
     broadcast re-places device-to-device (the fault path's degraded /
     orphan-repaired rounds re-place without host round-trips).  Only the
     stacked-replica algorithms support it (fedpairing on the vmapped /
-    bucketed engines, fl); the dist engine owns its own mesh and the
-    sl/splitfed relays train single trees.
+    bucketed engines, fl); the sl/splitfed relays train single trees,
+    and the dist engine owns its own one-client-per-device mesh, which
+    the driver then uses as its placement.
     """
 
     def __init__(self, cfg, rc: RoundConfig, fleet: ClientFleet,
@@ -481,13 +484,10 @@ class RoundDriver:
         self.init_fn = init_fn or (lambda key: registry.init_params(cfg, key))
         self.batch_fn = _validated_batch_fn(
             batch_fn or make_lm_batch_fn(cfg, self.n, seed=rc.seed), self.n)
-        if sharding is not None:
-            # batches are fleet state too: place every drawn batch with
-            # its client dim over the fleet axis (host-to-device, once
-            # per draw — the engines then never re-lay it out)
-            raw_batch_fn = self.batch_fn
-            self.batch_fn = lambda: sharding.place(raw_batch_fn())
-        self._gparams = self.init_fn(jax.random.key(rc.seed))
+        # the seeded initial global model, kept on the host: at published
+        # widths a second device-resident copy beside the N replicas is
+        # what tips one chip's memory over (``_init_global`` copies it in)
+        self._gparams = jax.device_get(self.init_fn(jax.random.key(rc.seed)))
         self._engine = None
         self._baseline_step = None
         # cross-round cut-search cache (DESIGN.md §8): re-plans of a kept
@@ -525,11 +525,26 @@ class RoundDriver:
         if rc.algorithm == "fedpairing":
             self._engine = _ENGINE_CLASSES[rc.engine](
                 cfg, rc, self.n, self._gparams, self.loss_fn)
+            if rc.engine == "dist":
+                # the engine's one-client-per-device mesh is the fleet
+                # placement: replicas, batches and broadcasts live one
+                # client per device instead of all on the default device
+                self.sharding = FleetSharding(mesh=self._engine.mesh)
+        if self.sharding is not None:
+            # batches are fleet state too: place every drawn batch with
+            # its client dim over the fleet axis (host-to-device, once
+            # per draw — the engines then never re-lay it out)
+            raw_batch_fn, place = self.batch_fn, self.sharding.place
+            self.batch_fn = lambda: place(raw_batch_fn())
 
     # -- state ------------------------------------------------------------
 
+    def _init_global(self) -> Dict:
+        """A device copy of the seeded initial global model."""
+        return jax.tree_util.tree_map(jnp.asarray, self._gparams)
+
     def init_state(self) -> RoundState:
-        g = self._gparams
+        g = self._init_global()
         if self.rc.algorithm == "sl":
             client, server = g, g
         elif self.rc.algorithm == "splitfed":
@@ -544,8 +559,7 @@ class RoundDriver:
                           clock=(latency.initial_event_clock(self.n)
                                  if self.rc.async_rounds else None),
                           agg=self.agg_policy.init_state(
-                              self._gparams, self.n,
-                              sharding=self.sharding))
+                              g, self.n, sharding=self.sharding))
 
     def global_params(self, state: RoundState) -> Dict:
         """The post-broadcast global model.  For sl the single shared tree;
@@ -658,7 +672,7 @@ class RoundDriver:
                 f"{ckpt_agg!r}; this driver has agg_policy="
                 f"{self.agg_policy.spec!r} — the control-variate state is "
                 f"part of the resumed trace")
-        g = self._gparams
+        g = self._init_global()
         if self.rc.algorithm == "sl":
             client_like, server_like = g, g
         elif self.rc.algorithm == "splitfed":
@@ -700,7 +714,7 @@ class RoundDriver:
         agg = (self.agg_policy.restore_state(tree["agg"], meta,
                                              sharding=self.sharding)
                if "agg" in tree
-               else self.agg_policy.init_state(self._gparams, self.n,
+               else self.agg_policy.init_state(g, self.n,
                                                sharding=self.sharding))
         if fast_forward:
             for _ in range(int(meta["round"]) * self.rc.batches_per_round):

@@ -10,6 +10,11 @@ scores (block_q, block_k) fp32 — all MXU-aligned multiples of 128 for the
 full-size configs (128x128 blocks x head_dim<=128 => ~200 KB working set,
 comfortably inside the ~16 MB v5e VMEM with double buffering).
 
+Layout: the kernel sees head-major (B, H, S, d) arrays, so every block's
+last two dims are (rows, head_dim) — the TPU lowering requires them to be
+(8, 128)-aligned or whole, which a head axis blocked at 1 just before the
+feature axis is not.  The wrapper transposes at the kernel boundary.
+
 GQA is handled in the index maps (kv head = q head // group) — repeated KV
 heads are never materialized.
 """
@@ -22,8 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro import compat
 
 NEG_INF = -1e30
 
@@ -40,9 +43,9 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)            # (bq, d)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bk, d)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)            # (bk, d)
+    q = q_ref[0, 0].astype(jnp.float32)                  # (bq, d)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (bk, d)
+    v = v_ref[0, 0].astype(jnp.float32)                  # (bk, d)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
@@ -58,22 +61,22 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
         mask &= kpos > qpos - sliding_window
     s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    m_prev = m_ref[...]                                  # (bq, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None])
+    p = jnp.exp(s - m_new)
     # fully-masked rows: exp(NEG_INF - NEG_INF) = 1 would pollute; zero them
     p = jnp.where(mask, p, 0.0)
 
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1)
-    acc_ref[...] = alpha[:, None] * acc_ref[...] + jax.lax.dot_general(
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = alpha * acc_ref[...] + jax.lax.dot_general(
         p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
     m_ref[...] = m_new
 
     @pl.when(ik == n_kv_blocks - 1)
     def _finish():
         denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "sliding_window",
@@ -96,26 +99,29 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         _attn_kernel, scale=scale, block_q=block_q, block_k=block_k,
         n_kv_blocks=n_k, causal=causal, sliding_window=sliding_window)
 
-    return pl.pallas_call(
+    heads_major = lambda a: a.transpose(0, 2, 1, 3)      # (B, H, S, d)
+    out = pl.pallas_call(
         kernel,
         grid=(B, Hq, n_q, n_k),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, d), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda b, h, iq, ik, G=G: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, d),
-                         lambda b, h, iq, ik, G=G: (b, ik, h // G, 0)),
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, block_k, d),
+                         lambda b, h, iq, ik, G=G: (b, h // G, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, d),
+                         lambda b, h, iq, ik, G=G: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, d),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, d),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, S, d), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v)
+    )(heads_major(q), heads_major(k), heads_major(v))
+    return heads_major(out)

@@ -9,10 +9,16 @@ Each op has (up to) three implementations:
 
 Selection: explicit ``impl=`` argument > ``set_default_impl()`` > backend
 default (``pallas`` on TPU, ``xla`` elsewhere).
+
+The Pallas kernels are forward-only: differentiating through one raises
+``NotImplementedError`` naming the kernel (``pallas_call`` has no
+transpose rule, and a silent fall-back to ``xla`` would train through a
+different code path than the one the chip runs).
 """
 from __future__ import annotations
 
-from typing import Optional, Tuple
+import functools
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +44,27 @@ def resolve_impl(impl: Optional[str] = None) -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
+def _forward_only(name: str, kernel: Callable) -> Callable:
+    """``kernel`` with a backward pass that raises, naming the kernel."""
+
+    @jax.custom_vjp
+    def f(*args):
+        return kernel(*args)
+
+    def fwd(*args):
+        return kernel(*args), None
+
+    def bwd(_, g):
+        raise NotImplementedError(
+            f"the {name} Pallas kernel has no backward pass: training "
+            f"through it (the default on a TPU) is not supported yet; "
+            f"call repro.kernels.ops.set_default_impl('xla') to train "
+            f"through the jnp reference instead")
+
+    f.defvjp(fwd, bwd)
+    return f
+
+
 # ---------------------------------------------------------------------------
 # flash attention
 # ---------------------------------------------------------------------------
@@ -51,9 +78,9 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
         return ref.attention_ref(q, k, v, causal=causal,
                                  sliding_window=sliding_window)
     from repro.kernels import flash_attention as fa
-    return fa.flash_attention(q, k, v, causal=causal,
-                              sliding_window=sliding_window,
-                              interpret=(which == "pallas_interpret"))
+    return _forward_only("flash_attention", functools.partial(
+        fa.flash_attention, causal=causal, sliding_window=sliding_window,
+        interpret=(which == "pallas_interpret")))(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -68,9 +95,10 @@ def ssd(x: jnp.ndarray, log_decay: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray,
         return ref.ssd_chunked_ref(x, log_decay, b, c, chunk=chunk,
                                    initial_state=initial_state)
     from repro.kernels import ssd_scan
-    return ssd_scan.ssd(x, log_decay, b, c, chunk=chunk,
-                        initial_state=initial_state,
-                        interpret=(which == "pallas_interpret"))
+    return _forward_only("ssd", lambda x, a, b, c, s0: ssd_scan.ssd(
+        x, a, b, c, chunk=chunk, initial_state=s0,
+        interpret=(which == "pallas_interpret")))(x, log_decay, b, c,
+                                                  initial_state)
 
 
 def ssd_decode(state: jnp.ndarray, x: jnp.ndarray, log_decay: jnp.ndarray,
@@ -113,10 +141,10 @@ def wkv6(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, log_w: jnp.ndarray,
         return ref.wkv6_chunked_ref(r, k, v, log_w, u, chunk=chunk,
                                     initial_state=initial_state)
     from repro.kernels import wkv6 as wkv6_kernel
-    return wkv6_kernel.wkv6(r, k, v, log_w, u,
-                            chunk=wkv6_effective_chunk(chunk, which),
-                            initial_state=initial_state,
-                            interpret=(which == "pallas_interpret"))
+    return _forward_only("wkv6", lambda r, k, v, lw, u, s0: wkv6_kernel.wkv6(
+        r, k, v, lw, u, chunk=wkv6_effective_chunk(chunk, which),
+        initial_state=s0, interpret=(which == "pallas_interpret")))(
+            r, k, v, log_w, u, initial_state)
 
 
 def wkv6_decode(state: jnp.ndarray, r: jnp.ndarray, k: jnp.ndarray,

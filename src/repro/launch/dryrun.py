@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=512").strip()
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) combo.
 
 Proves the distribution config is coherent without hardware:
@@ -10,8 +6,9 @@ Proves the distribution config is coherent without hardware:
 input shapes, then reports memory_analysis / cost_analysis / collective
 bytes for the roofline (EXPERIMENTS.md §Dry-run / §Roofline).
 
-NOTE: the XLA_FLAGS line above MUST run before any other import touches
-jax — 512 host placeholder devices are fabricated for this process only.
+NOTE: ``main`` fabricates 512 host placeholder devices for this process
+(``fabricate_host_devices``) before anything initializes jax's backend;
+importing this module changes no device state.
 
 Usage:
   python -m repro.launch.dryrun --arch tinyllama-1.1b --shape train_4k
@@ -20,6 +17,7 @@ Usage:
 
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -30,6 +28,14 @@ from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config, get_shape
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_step
 from repro.roofline import analysis
+
+
+def fabricate_host_devices(count: int = 512) -> None:
+    """Ask XLA for ``count`` placeholder CPU devices.  Only effective
+    before the first computation initializes the CPU backend."""
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("XLA_FLAGS", "")
+        + f" --xla_force_host_platform_device_count={count}").strip()
 
 
 def supported(arch_id: str, shape_id: str) -> bool:
@@ -53,7 +59,7 @@ def run_combo(arch_id: str, shape_id: str, multi_pod: bool,
 
     # Pass 1 — scan-over-layers program: this is the deployable artifact;
     # its memory_analysis has realistic buffer reuse ("proves it fits").
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, example, in_shardings, out_shardings = build_step(
             cfg, shape, mesh, unroll=1)
         jitted = jax.jit(fn, in_shardings=in_shardings,
@@ -133,7 +139,7 @@ def _extrapolated_cost(cfg, shape, mesh, d_pair=None):
         if cfg.is_encdec:
             over["num_encoder_layers"] = d
         cfg_d = cfg.with_overrides(**over)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn, ex, ins, outs = build_step(cfg_d, shape, mesh, unroll=True)
             comp = jax.jit(fn, in_shardings=ins,
                            out_shardings=outs).lower(*ex).compile()
@@ -155,7 +161,7 @@ def _run_fed_combo(arch_id, cfg, shape, mesh, mesh_name, chips, out_dir,
     """Dry-run the distributed FedPairing step (the paper's technique)."""
     from repro.launch.steps import build_fed_step
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, example, in_shardings, out_shardings = build_fed_step(
             cfg, shape, mesh, static_half_split=static, unroll=True,
             ce_chunk=ce_chunk)
@@ -197,6 +203,7 @@ def _run_fed_combo(arch_id, cfg, shape, mesh, mesh_name, chips, out_dir,
 
 
 def main() -> None:
+    fabricate_host_devices()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--shape", choices=list(INPUT_SHAPES))

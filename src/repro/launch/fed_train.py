@@ -31,16 +31,17 @@ from __future__ import annotations
 
 import argparse
 import time
+from typing import Optional
 
-from repro.configs import ARCH_IDS, get_smoke_config
+from repro.configs import ArchConfig
 from repro.core import aggregation, latency, planning, rounds
 from repro.core.latency import ChannelModel
-from repro.launch import fault_cli, fleet_cli
+from repro.launch import compile_cache, fault_cli, fleet_cli
 
 
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=ARCH_IDS, default="tinyllama-1.1b")
+    fleet_cli.add_arch_args(ap)
     ap.add_argument("--clients", type=int, default=8)
     ap.add_argument("--rounds", type=int, default=3)
     ap.add_argument("--batches-per-round", type=int, default=8)
@@ -102,13 +103,15 @@ def build_parser() -> argparse.ArgumentParser:
     return ap
 
 
-def main() -> None:
-    args = build_parser().parse_args()
-
-    cfg = get_smoke_config(args.arch)
+def build_driver(args: argparse.Namespace,
+                 cfg: Optional[ArchConfig] = None) -> rounds.RoundDriver:
+    """The ``RoundDriver`` the parsed flags describe: seeded fleet,
+    per-cut workload from the real architecture, round config, fleet
+    placement and synthetic LM batches (``chip_smoke.py`` builds its
+    drivers here too).  ``cfg`` replaces the architecture the flags
+    select."""
+    cfg = cfg or fleet_cli.arch_config(args)
     n = args.clients
-    fleet = latency.make_fleet(n=n, seed=args.seed)
-    chan = ChannelModel()
     # per-cut boundary payloads from the REAL architecture (residual
     # stream bytes per split depth), not the flat ResNet18 constant
     w = latency.workload_from_arch(cfg, seq_len=args.seq,
@@ -132,6 +135,21 @@ def main() -> None:
         async_rounds=args.async_rounds,
         staleness_bound=args.staleness_bound,
         overlap_planning=args.overlap_planning)
+    return rounds.RoundDriver(
+        cfg, rc, latency.make_fleet(n=n, seed=args.seed),
+        chan=ChannelModel(), workload=w,
+        batch_fn=rounds.make_lm_batch_fn(cfg, n, args.batch, args.seq,
+                                         args.seed),
+        sharding=fleet_cli.fleet_sharding_from_args(args))
+
+
+def main() -> None:
+    args = build_parser().parse_args()
+    compile_cache.enable_compile_cache()
+    driver = build_driver(args)
+    cfg, rc, fleet, chan, w = (driver.cfg, driver.rc, driver.fleet0,
+                               driver.chan, driver.workload)
+    n = driver.n
     # round-0 plan preview on the initial channel realization: the joint
     # plan (pairing x cut together) vs the sequential pair-then-cut plan
     plan0 = planning.build_joint_plan(
@@ -145,16 +163,9 @@ def main() -> None:
     print(f"[fed] modeled round time: "
           f"{latency.round_time_plan(plan0, fleet, chan, w):.1f}s "
           f"(vanilla FL {latency.round_time_vanilla_fl(fleet, chan, w):.1f}s)")
-
-    sharding = fleet_cli.fleet_sharding_from_args(args)
-    if sharding is not None:
-        print(f"[fed] fleet axis sharded over {sharding.num_shards} "
+    if args.fleet_sharding or args.mesh_shape:
+        print(f"[fed] fleet axis sharded over {driver.sharding.num_shards} "
               f"device(s)")
-    driver = rounds.RoundDriver(
-        cfg, rc, fleet, chan=chan, workload=w,
-        batch_fn=rounds.make_lm_batch_fn(cfg, n, args.batch, args.seq,
-                                         args.seed),
-        sharding=sharding)
     state = fault_cli.initial_state(driver, args)
     for _ in range(max(0, args.rounds - state.round)):
         t0 = time.time()

@@ -1,7 +1,9 @@
-"""Shared CLI surface for device-class fleets (per-client workloads) and
-fleet-axis sharding.
+"""Shared CLI surface for the model, device-class fleets (per-client
+workloads) and fleet-axis sharding.
 
 Both launchers (``fed_train``, ``sim``) expose the same
+``--arch``/``--published`` flags over ``configs.get_smoke_config`` /
+``configs.get_config``, the same
 ``--device-classes``/``--class-mix`` flags over
 ``core.latency.workload_for_classes`` (DESIGN.md §10) and the same
 ``--fleet-sharding``/``--mesh-shape`` flags over
@@ -13,7 +15,21 @@ from __future__ import annotations
 
 import argparse
 
+from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.core import latency
+
+
+def add_arch_args(ap: argparse.ArgumentParser) -> None:
+    ap.add_argument("--arch", choices=ARCH_IDS, default="tinyllama-1.1b")
+    ap.add_argument("--published", action="store_true",
+                    help="train --arch at its published widths and depth "
+                         "(configs.get_config) instead of the reduced "
+                         "smoke config")
+
+
+def arch_config(args: argparse.Namespace):
+    """The ``ArchConfig`` the flags select."""
+    return (get_config if args.published else get_smoke_config)(args.arch)
 
 
 def add_fleet_args(ap: argparse.ArgumentParser) -> None:

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=512").strip()
-
 """§Perf hillclimbing driver: compile a (arch x shape) combo under a named
 variant, report the three roofline terms + memory, append to the perf log.
 
@@ -19,12 +15,11 @@ import time
 
 import jax
 
-from repro import compat
 from repro.configs import ARCH_IDS, INPUT_SHAPES, get_config, get_shape
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import (build_fed_step, build_serve_step,
                                 build_train_step)
-from repro.launch.dryrun import _extrapolated_cost
+from repro.launch.dryrun import _extrapolated_cost, fabricate_host_devices
 from repro.roofline import analysis
 
 TRAIN_VARIANTS = {
@@ -71,7 +66,7 @@ def run(arch_id: str, shape_id: str, variant: str, out_dir: str) -> dict:
             c, shape, mesh, unroll=unroll, **kw)
 
     # memory pass (scan program)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, ex, ins, outs = builder(cfg, 1)
         compiled = jax.jit(fn, in_shardings=ins,
                            out_shardings=outs).lower(*ex).compile()
@@ -120,6 +115,7 @@ def run(arch_id: str, shape_id: str, variant: str, out_dir: str) -> dict:
 
 
 def main() -> None:
+    fabricate_host_devices()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, required=True)
     ap.add_argument("--shape", choices=list(INPUT_SHAPES), required=True)

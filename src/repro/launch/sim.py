@@ -19,15 +19,14 @@ import dataclasses
 import json
 import time
 
-from repro.configs import ARCH_IDS, get_smoke_config
 from repro.core import aggregation, latency, rounds
 from repro.core.latency import ChannelModel
-from repro.launch import fault_cli, fleet_cli
+from repro.launch import compile_cache, fault_cli, fleet_cli
 
 
 def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", choices=ARCH_IDS, default="tinyllama-1.1b")
+    fleet_cli.add_arch_args(ap)
     ap.add_argument("--algorithm", choices=rounds.ALGORITHMS,
                     default="fedpairing")
     ap.add_argument("--engine", choices=rounds.ENGINES, default="vmapped")
@@ -100,7 +99,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def run_sim(args) -> rounds.RoundState:
-    cfg = get_smoke_config(args.arch)
+    cfg = fleet_cli.arch_config(args)
     rc = rounds.RoundConfig(
         algorithm=args.algorithm, engine=args.engine,
         pair_mechanism=args.pairing, pair_policy=args.pair_policy,
@@ -171,7 +170,9 @@ def run_sim(args) -> rounds.RoundState:
 
 
 def main() -> None:
-    run_sim(build_parser().parse_args())
+    args = build_parser().parse_args()
+    compile_cache.enable_compile_cache()
+    run_sim(args)
 
 
 if __name__ == "__main__":

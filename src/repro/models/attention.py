@@ -21,7 +21,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.models import common
 
 NEG_INF = -1e30
@@ -236,7 +235,7 @@ def decode_attend_seq_parallel(q: jnp.ndarray, cache_k: jnp.ndarray,
         out = o / l.transpose(0, 3, 1, 2)[..., None]
         return out.reshape(q_l.shape[0], 1, Hq, d).astype(q_l.dtype)
 
-    return compat.shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bx, None, None, None), P(bx, "model", None, None),
                   P(bx, "model", None, None), P()),

@@ -20,7 +20,6 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ArchConfig
 from repro.models import common
 
@@ -233,7 +232,7 @@ def moe_apply_ep(p: Dict[str, jnp.ndarray], x: jnp.ndarray, cfg: ArchConfig,
     in_specs = (P(axes), P(), P(axes), P(axes), P(axes),
                 None if shared_p is None else jax.tree_util.tree_map(
                     lambda _: P(), shared_p))
-    out, aux = compat.shard_map(
+    out, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=in_specs,
         out_specs=(P(axes), P(axes)),

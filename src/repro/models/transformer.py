@@ -235,5 +235,10 @@ def embed(params: Dict, tokens: jnp.ndarray, cfg: ArchConfig,
 
 def lm_logits(params: Dict, x: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
     h = common.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    w = params["embed"].T if cfg.tie_embeddings else params["unembed"]
+    if cfg.tie_embeddings:
+        # a paired split flow runs its head on the partner's copy of the
+        # tied matrix, which the vmapped engine passes as ``tied_head``
+        w = params.get("tied_head", params["embed"]).T
+    else:
+        w = params["unembed"]
     return jnp.einsum("bsd,dv->bsv", h, w.astype(h.dtype))

@@ -1,18 +1,11 @@
-"""``repro.compat`` shim coverage (satellite of DESIGN.md §11): the
-mesh / shard_map / set_mesh facade, exercised
-
-* for real on the current runtime — a 1-device mesh in process, and a
-  fabricated 4-device host platform in a subprocess (XLA_FLAGS must be
-  set before the first jax import);
-* for BOTH dispatch paths the shim claims to support — the jax >= 0.6
-  spelling (``jax.shard_map``/``jax.set_mesh``, ``axis_names``/
-  ``check_vma``) and the 0.4.x spelling (``jax.experimental.shard_map``,
-  ``auto``-complement/``check_rep``, mesh-as-context-manager) — via
-  stubbed modules, since only one runtime is ever installed.
+"""``repro.compat`` coverage (satellite of DESIGN.md §11): meshes built
+with Auto axes work with ``jax.shard_map`` / ``jax.set_mesh``, exercised
+for real — a 1-device mesh in process, and a fabricated 4-device host
+platform in a subprocess (XLA_FLAGS must be set before the first jax
+import) — plus the flat cost-analysis dict.
 """
 import subprocess
 import sys
-import types
 
 import jax
 import jax.numpy as jnp
@@ -37,12 +30,12 @@ class TestOneDeviceReal:
 
     def test_set_mesh_is_context_manager(self):
         mesh = compat.make_mesh((1,), ("data",))
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             pass                        # entering/exiting must not raise
 
     def test_shard_map_psum_identity(self):
         mesh = compat.make_mesh((1,), ("data",))
-        f = compat.shard_map(
+        f = jax.shard_map(
             lambda x: jax.lax.psum(x, "data"), mesh=mesh,
             in_specs=P("data"), out_specs=P())
         x = jnp.arange(4, dtype=jnp.float32).reshape(1, 4)
@@ -51,7 +44,7 @@ class TestOneDeviceReal:
 
     def test_shard_map_axis_names_subset(self):
         mesh = compat.make_mesh((1, 1), ("data", "model"))
-        f = compat.shard_map(
+        f = jax.shard_map(
             lambda x: x * 2.0, mesh=mesh, in_specs=P("data"),
             out_specs=P("data"), axis_names={"data", "model"})
         x = jnp.ones((2, 3))
@@ -78,20 +71,20 @@ assert mesh.axis_names == ("data",) and mesh.devices.size == 4
 
 # shard_map psum across the fabricated axis: every shard sees the sum
 # (each shard holds a (1, 2) block, so the replicated output keeps it)
-f = compat.shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
+f = jax.shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
                      in_specs=P("data"), out_specs=P())
 x = jnp.arange(8, dtype=jnp.float32).reshape(4, 2)
 np.testing.assert_array_equal(np.asarray(f(x)),
                               np.asarray(x).sum(axis=0, keepdims=True))
 
 # per-shard identity keeps the sharded layout
-g = compat.shard_map(lambda x: x + 1.0, mesh=mesh,
+g = jax.shard_map(lambda x: x + 1.0, mesh=mesh,
                      in_specs=P("data"), out_specs=P("data"))
 y = g(jax.device_put(x, NamedSharding(mesh, P("data"))))
 np.testing.assert_array_equal(np.asarray(y), np.asarray(x) + 1.0)
 
 # ambient mesh: jit under set_mesh resolves named shardings
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     z = jax.jit(lambda a: a * 2.0)(
         jax.device_put(x, NamedSharding(mesh, P("data"))))
 np.testing.assert_array_equal(np.asarray(z), np.asarray(x) * 2.0)
@@ -114,117 +107,25 @@ def test_multi_device_compat_real():
         res.stdout[-2000:] + res.stderr[-4000:]
 
 
-# ---------------------------------------------------------------------------
-# dispatch-path translation: both runtimes' spellings, stubbed
-# ---------------------------------------------------------------------------
-
-class _Mesh:
-    axis_names = ("data", "model")
-
-
-class TestNewApiDispatch:
-    """jax >= 0.6 path: jax.shard_map / jax.set_mesh spellings."""
-
-    def test_shard_map_forwards_modern_kwargs(self, monkeypatch):
-        seen = {}
-
-        def fake_shard_map(f, *, mesh, in_specs, out_specs, **kw):
-            seen.update(kw, mesh=mesh)
-            return f
-
-        monkeypatch.setattr(compat, "_HAS_NEW_SHARD_MAP", True)
-        monkeypatch.setattr(compat.jax, "shard_map", fake_shard_map,
-                            raising=False)
-        mesh = _Mesh()
-        out = compat.shard_map(lambda x: x, mesh=mesh, in_specs=P("data"),
-                               out_specs=P(), axis_names={"data"},
-                               check_vma=True)
-        assert out(7) == 7
-        assert seen["mesh"] is mesh
-        assert seen["axis_names"] == {"data"}
-        assert seen["check_vma"] is True
-
-    def test_shard_map_omits_axis_names_when_none(self, monkeypatch):
-        seen = {}
-        monkeypatch.setattr(compat, "_HAS_NEW_SHARD_MAP", True)
-        monkeypatch.setattr(
-            compat.jax, "shard_map",
-            lambda f, **kw: seen.update(kw) or f, raising=False)
-        compat.shard_map(lambda x: x, mesh=_Mesh(), in_specs=P(),
-                         out_specs=P())
-        assert "axis_names" not in seen
-        assert seen["check_vma"] is False
-
-    def test_set_mesh_prefers_jax_set_mesh(self, monkeypatch):
-        seen = {}
-        monkeypatch.setattr(compat, "_HAS_SET_MESH", True)
-        monkeypatch.setattr(compat.jax, "set_mesh",
-                            lambda m: (seen.update(mesh=m), "ctx")[1],
-                            raising=False)
-        assert compat.set_mesh("MESH") == "ctx"
-        assert seen["mesh"] == "MESH"
-
-
-class TestOldApiDispatch:
-    """0.4.x path: jax.experimental.shard_map with the complementary
-    ``auto`` set and ``check_rep``."""
-
-    def _install_old(self, monkeypatch, seen):
-        def old_shard_map(f, mesh, *, in_specs, out_specs, check_rep,
-                          auto):
-            seen.update(mesh=mesh, check_rep=check_rep, auto=auto)
-            return f
-
-        mod = types.ModuleType("jax.experimental.shard_map")
-        mod.shard_map = old_shard_map
-        monkeypatch.setitem(sys.modules, "jax.experimental.shard_map", mod)
-        monkeypatch.setattr(compat, "_HAS_NEW_SHARD_MAP", False)
-
-    def test_axis_names_complement_becomes_auto(self, monkeypatch):
-        seen = {}
-        self._install_old(monkeypatch, seen)
-        compat.shard_map(lambda x: x, mesh=_Mesh(), in_specs=P("data"),
-                         out_specs=P(), axis_names={"data"},
-                         check_vma=True)
-        # manual {"data"} over a ("data","model") mesh -> auto {"model"}
-        assert seen["auto"] == frozenset({"model"})
-        assert seen["check_rep"] is True
-
-    def test_default_axis_names_means_fully_manual(self, monkeypatch):
-        seen = {}
-        self._install_old(monkeypatch, seen)
-        compat.shard_map(lambda x: x, mesh=_Mesh(), in_specs=P(),
-                         out_specs=P())
-        assert seen["auto"] == frozenset()
-        assert seen["check_rep"] is False
-
-    def test_set_mesh_falls_back_to_mesh_context(self, monkeypatch):
-        monkeypatch.setattr(compat, "_HAS_SET_MESH", False)
-        mesh = compat.make_mesh((1,), ("data",))
-        assert compat.set_mesh(mesh) is mesh   # Mesh IS the context mgr
-
-
 class TestMakeMeshAxisTypes:
     def test_axis_types_attached_when_supported(self, monkeypatch):
         seen = {}
-        monkeypatch.setattr(compat, "_HAS_AXIS_TYPE", True)
         monkeypatch.setattr(
             compat.jax, "make_mesh",
-            lambda shapes, names, **kw: seen.update(kw) or "mesh",
-            raising=False)
-        fake_axis_type = types.SimpleNamespace(Auto="AUTO")
-        monkeypatch.setattr(compat.jax.sharding, "AxisType",
-                            fake_axis_type, raising=False)
-        assert compat.make_mesh((2, 2), ("data", "model")) == "mesh"
-        assert seen["axis_types"] == ("AUTO", "AUTO")
-
-    def test_no_axis_types_on_old_runtime(self, monkeypatch):
-        seen = {}
-        monkeypatch.setattr(compat, "_HAS_AXIS_TYPE", False)
-        monkeypatch.setattr(
-            compat.jax, "make_mesh",
-            lambda shapes, names, **kw: seen.update(kw) or "mesh",
-            raising=False)
-        compat.make_mesh((1,), ("data",), devices=["d0"])
-        assert "axis_types" not in seen
+            lambda shapes, names, **kw: seen.update(kw) or "mesh")
+        assert compat.make_mesh((2, 2), ("data", "model"),
+                                devices=["d0"]) == "mesh"
+        assert seen["axis_types"] == (jax.sharding.AxisType.Auto,) * 2
         assert seen["devices"] == ["d0"]
+
+    def test_real_mesh_axes_are_auto(self):
+        mesh = compat.make_mesh((1, 1), ("data", "model"))
+        assert mesh.axis_types == (jax.sharding.AxisType.Auto,) * 2
+
+
+def test_cost_analysis_is_a_flat_dict():
+    compiled = jax.jit(lambda a, b: a @ b).lower(
+        jnp.ones((8, 8)), jnp.ones((8, 8))).compile()
+    cost = compat.cost_analysis(compiled)
+    assert isinstance(cost, dict)
+    assert cost["flops"] > 0

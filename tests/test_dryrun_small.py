@@ -25,7 +25,7 @@ for arch in ["tinyllama-1.1b", "deepseek-moe-16b", "rwkv6-1.6b",
     cfg = get_smoke_config(arch).with_overrides(dtype="bfloat16")
     for shape in [InputShape("train", 64, 8, "train"),
                   InputShape("decode", 64, 8, "decode")]:
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             fn, ex, ins, outs = build_step(cfg, shape, mesh, unroll=True)
             compiled = jax.jit(fn, in_shardings=ins,
                                out_shardings=outs).lower(*ex).compile()

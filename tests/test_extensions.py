@@ -139,7 +139,7 @@ shape = InputShape("train", 32, 8, "train")
 key = jax.random.key(0)
 outs = {}
 for mb in (1, 4):
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, ex, ins, osh = build_train_step(cfg, shape, mesh, microbatches=mb)
         jitted = jax.jit(fn, in_shardings=ins, out_shardings=osh)
         params = jax.device_put(R.init_params(cfg, key), ins[0])

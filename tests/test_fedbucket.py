@@ -158,6 +158,35 @@ def test_bucketed_matches_vmapped_mix_core(aggregation):
     _tree_allclose(new_v, new_b, rtol=5e-4, atol=5e-5)
 
 
+@pytest.mark.parametrize("aggregation", ["paper", "fedavg"])
+def test_bucketed_matches_vmapped_mix_core_with_tied_embeddings(aggregation):
+    """With a tied head the flow's head runs on the partner's embedding
+    matrix in both engines (``fedpair.make_fed_step(tied_head=True)``)."""
+    cfg = get_smoke_config("qwen1.5-0.5b").with_overrides(num_layers=W)
+    assert cfg.tie_embeddings
+    n = 4
+    partner, lengths = np.array([1, 0, 3, 2]), np.array([1, 3, 2, 2])
+    g = registry.init_params(cfg, jax.random.key(0))
+    cp = fedpair.replicate(g, n)
+    tokens = jax.random.randint(jax.random.key(1), (n, 2, 16), 0,
+                                cfg.vocab_size)
+    batch = {"tokens": tokens, "labels": tokens}
+    agg_w = fedpair.pair_weights(np.arange(1.0, n + 1), partner)
+    step_b, _ = fedbucket.make_bucketed_fed_step(
+        cfg, partner, lengths, agg_w,
+        fedbucket.FedBucketConfig(lr=0.1, aggregation=aggregation,
+                                  donate=False))
+    new_b, _ = step_b(cp, batch)
+    step_v = fedpair.make_fed_step(
+        lambda p, b: registry.loss_fn(p, b, cfg)[0],
+        splitting.split_plan(cfg, g), W,
+        fedpair.FedPairingConfig(lr=0.1 / n, aggregation=aggregation,
+                                 donate=False), tied_head=True)
+    new_v, _ = step_v(cp, batch, jnp.asarray(partner), jnp.asarray(lengths),
+                      jnp.asarray(agg_w))
+    _tree_allclose(new_v, new_b, rtol=5e-4, atol=5e-5)
+
+
 def test_step_donates_client_params():
     cp, batch = _setup(2)
     step, _ = fedbucket.make_bucketed_fed_step(

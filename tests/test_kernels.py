@@ -175,6 +175,29 @@ class TestDispatch:
             ops.set_default_impl("cuda")
 
 
+class TestForwardOnly:
+    """The Pallas kernels have no backward pass yet: differentiating
+    through one must name it, never fall back to the reference."""
+
+    CASES = {
+        "flash_attention": lambda x: ops.flash_attention(
+            x, x, x, impl="pallas_interpret"),
+        "ssd": lambda x: ops.ssd(
+            x, -jnp.ones(x.shape[:3]), x[:, :, 0], x[:, :, 0], chunk=16,
+            impl="pallas_interpret")[0],
+        "wkv6": lambda x: ops.wkv6(
+            x, x, x, -jnp.abs(x), x[0, 0], impl="pallas_interpret")[0],
+    }
+
+    @pytest.mark.parametrize("name", sorted(CASES))
+    def test_grad_names_the_missing_backward(self, name):
+        x = jax.random.normal(KEY, (1, 64, 2, 16)) * 0.1
+        fwd = self.CASES[name]
+        assert fwd(x).shape[:2] == (1, 64)          # forward still runs
+        with pytest.raises(NotImplementedError, match=name):
+            jax.grad(lambda a: fwd(a).sum())(x)
+
+
 class TestWKV6EffectiveChunk:
     """The Pallas wkv6 kernel coerces sub-64 chunk requests up to its
     minimum sequence tile; the coercion is explicit and queryable."""

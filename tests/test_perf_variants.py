@@ -14,7 +14,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro import compat
 from repro.configs import get_smoke_config
 from repro.models import registry
 
@@ -48,7 +47,7 @@ cfg = get_smoke_config("deepseek-moe-16b").with_overrides(
     num_experts=4, expert_pad_to=4, moe_capacity_factor=8.0)
 p = moe.moe_init(jax.random.key(0), None, cfg)
 x = jax.random.normal(jax.random.key(1), (8, 16, cfg.d_model))
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     y0, a0 = jax.jit(lambda p, x: moe.moe_apply(p, x, cfg))(p, x)
     y1, a1 = jax.jit(lambda p, x: moe.moe_apply_ep(p, x, cfg, mesh,
                                                    ("data",)))(p, x)
@@ -62,7 +61,7 @@ cfg = get_smoke_config("tinyllama-1.1b")
 shape = InputShape("decode", 64, 4, "decode")
 outs = {}
 for fd in (False, True):
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         fn, ex, ins, osh = build_serve_step(cfg, shape, mesh, flash_decode=fd)
         jitted = jax.jit(fn, in_shardings=ins, out_shardings=osh)
         params = jax.device_put(registry.init_params(cfg, jax.random.key(0)),
@@ -82,11 +81,6 @@ print("FLASH_DECODE_OK")
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not compat.PARTIAL_AUTO_SHARD_MAP,
-    reason="EP dispatch / flash decode use partial-manual shard_map "
-           "(manual client axes + auto model axis), which CHECK-fails in "
-           "this jax runtime's SPMD partitioner; needs jax >= 0.6")
 def test_ep_and_flash_decode_equivalence():
     res = subprocess.run(
         [sys.executable, "-c", _SUBPROC], capture_output=True, text=True,

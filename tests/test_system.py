@@ -158,7 +158,7 @@ new_v, _ = step_v(cp, batch, jnp.asarray(partner), jnp.asarray(lengths),
 from repro import compat
 mesh = compat.make_mesh((4,), ("data",))
 dcfg = fedpair_dist.FedDistConfig(lr=0.1)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     step_d = fedpair_dist.make_dist_fed_step(
         cfg, mesh, fedpair_dist.pairs_to_ppermute(partner), agg_w, masks, dcfg)
     new_d, _ = step_d(cp, batch)
