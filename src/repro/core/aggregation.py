@@ -44,6 +44,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
+
 
 class EmptyCohortError(ValueError):
     """``aggregate()`` was asked to average an empty cohort (all-False
@@ -71,8 +73,10 @@ def _unnormalized_weights(agg_w: jnp.ndarray, mode: str,
     plain-mean fast path (mode "paper", no mask, no staleness) — the one
     place the mode × mask × staleness composition is defined, shared by
     ``aggregate`` and ``aggregation_weights``."""
-    if staleness is not None and not bool(jnp.any(staleness)):
-        staleness = None        # all fresh: keep the synchronous jaxpr
+    if staleness is not None:
+        telemetry.count("host_sync")
+        if not bool(jnp.any(staleness)):
+            staleness = None        # all fresh: keep the synchronous jaxpr
     if mode == "paper":
         if active is None and staleness is None:
             return None
@@ -135,6 +139,7 @@ def aggregate(client_params: Dict, agg_w: jnp.ndarray,
         return jax.tree_util.tree_map(lambda a: jnp.mean(a, axis=0),
                                       client_params)
     total = jnp.sum(w)
+    telemetry.count("host_sync")
     if float(total) <= 0.0:
         raise EmptyCohortError(round_idx)
     return _masked_weighted_mean(w / total, client_params)
@@ -153,6 +158,7 @@ def aggregation_weights(agg_w: jnp.ndarray, n: int, mode: str = "paper",
     if w is None:
         return jnp.full((n,), 1.0 / n, jnp.float32)
     total = jnp.sum(w)
+    telemetry.count("host_sync")
     if float(total) <= 0.0:
         raise EmptyCohortError(round_idx)
     return w / total
@@ -334,8 +340,11 @@ class ScaffoldAggregation(AggregationPolicy):
                 g, cbar, state.c_global)
 
         # -- variate refresh over the trained cohort ----------------------
-        act = (np.ones(n, bool) if active is None
-               else np.asarray(active, bool))
+        if active is None:
+            act = np.ones(n, bool)
+        else:
+            telemetry.count("host_sync")
+            act = np.asarray(active, bool)
         partner = np.asarray(ctx.partner, np.int64)
         lengths = np.asarray(ctx.lengths, np.float64)
         W = float(ctx.num_layers)

@@ -62,7 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
+from repro import compat, telemetry
 from repro.core import aggregation, baselines, fedpair, latency, pairing
 from repro.core import faults, participation, planning, splitting
 from repro.core.latency import ChannelModel, ClientFleet, WorkloadModel
@@ -293,10 +293,12 @@ class _VmappedEngine:
         self.cached_steps = 1
 
     def step(self, params, batch, plan: RoundPlan, agg_w):
-        new, m = self._step(params, batch,
-                            jnp.asarray(plan.partner_array(), jnp.int32),
-                            jnp.asarray(plan.lengths_array(), jnp.int32),
-                            jnp.asarray(agg_w, jnp.float32))
+        with telemetry.span("step.args"):
+            partner = jnp.asarray(plan.partner_array(), jnp.int32)
+            lengths = jnp.asarray(plan.lengths_array(), jnp.int32)
+            w = jnp.asarray(agg_w, jnp.float32)
+        with telemetry.span("step.dispatch"):
+            new, m = self._step(params, batch, partner, lengths, w)
         return new, m["loss"]
 
     def prebuild(self, plan: RoundPlan, agg_w) -> bool:
@@ -738,6 +740,10 @@ class RoundDriver:
         the input parameter buffers in place — re-running the *training*
         of a kept snapshot additionally needs ``RoundConfig(donate=False)``
         (the equivalence tests do)."""
+        with telemetry.span("round"):
+            return self._run_round(state)
+
+    def _run_round(self, state: RoundState) -> RoundState:
         rc = self.rc
         rng = copy.deepcopy(state.rng)
         fleet = latency.drift_fleet(state.fleet, rng, rc.drift_sigma_m)
@@ -763,6 +769,21 @@ class RoundDriver:
             history=state.history + [record], plan=plan, clock=clock,
             agg=agg)
 
+    def _next_batch(self) -> Dict:
+        with telemetry.span("batch"):
+            return self.batch_fn()
+
+    def _train(self, step: Callable, params) -> Tuple[Dict, List]:
+        """``batches_per_round`` local steps ``params, loss = step(params,
+        batch)``; returns the params and the per-step losses on the host."""
+        losses = []
+        for _ in range(self.rc.batches_per_round):
+            batch = self._next_batch()
+            with telemetry.span("step"):
+                params, loss = step(params, batch)
+            losses.append(_host_loss(loss))
+        return params, losses
+
     def _record(self, state, cohort, pairs, lengths, mean_loss, round_s,
                 cached, objective=None, replanned=True,
                 cut_cache="n/a", status="ok", failed=(),
@@ -787,7 +808,7 @@ class RoundDriver:
         the same batches regardless of cohort sizes (the checkpoint/resume
         fast-forward contract)."""
         for _ in range(self.rc.batches_per_round):
-            self.batch_fn()
+            self._next_batch()
         cached = self._engine.cached_steps if self._engine is not None else 1
         rec = self._record(state, cohort, (),
                            (self.cfg.num_layers,) * self.n, float("nan"),
@@ -998,45 +1019,51 @@ class RoundDriver:
 
     def _fedpairing_round(self, state, fleet, cohort, active, pair_seed):
         rc = self.rc
-        plan, anchor, replanned = self._adaptive_plan(state, fleet, cohort,
-                                                      active, pair_seed)
-        if self.fault_model.enabled:
+        faulted = self.fault_model.enabled
+        with telemetry.span("plan"):
+            plan, anchor, replanned = self._adaptive_plan(
+                state, fleet, cohort, active, pair_seed)
+            if not faulted:
+                partner = plan.partner_array()
+                agg_w = fedpair.pair_weights(fleet.data_sizes, partner)
+                g_prev = self._agg_snapshot(state)
+        if faulted:
             return self._fedpairing_faulted(state, fleet, cohort, active,
                                             plan, anchor, replanned)
-        partner = plan.partner_array()
-        agg_w = fedpair.pair_weights(fleet.data_sizes, partner)
-        g_prev = self._agg_snapshot(state)
-        params = state.client_params
-        losses = []
-        for _ in range(rc.batches_per_round):
-            params, l = self._engine.step(params, self.batch_fn(), plan,
-                                          agg_w)
-            losses.append(np.asarray(l))
+        params, losses = self._train(
+            lambda p, b: self._engine.step(p, b, plan, agg_w),
+            state.client_params)
         mean_loss = _mean_active_loss(losses, active,
                                       round_idx=state.round)
-        units, times, upload_s = latency.round_clock_plan(
-            self._latency_plan(fleet, partner, active, plan), fleet,
-            self.chan, self.workload)
-        if rc.async_rounds:
-            clock, ac = self._advance_clock(state, cohort, units, times,
-                                            upload_s)
-            round_s, wait_s, overlap_s = ac.round_s, ac.wait_s, ac.overlap_s
-        else:
-            clock, ac = state.clock, None
-            round_s = float(np.max(times)) + upload_s
-            wait_s, overlap_s = latency.barrier_wait_s(times), 0.0
-        g, agg = self._aggregate(
-            state, params, fleet, active, ac, rc.aggregation,
-            self._agg_ctx(g_prev, partner, plan.lengths_array(),
-                          eta=rc.lr / self.n))
-        params = aggregation.broadcast(g, self.n, sharding=self.sharding)
-        rec = self._record(state, cohort, plan.pairs, plan.lengths,
-                           mean_loss, round_s, self._engine.cached_steps,
-                           objective=plan.objective, replanned=replanned,
-                           cut_cache=self._cut_cache_status(replanned),
-                           wait_s=wait_s, overlap_s=overlap_s)
-        if rc.overlap_planning:
-            self._overlap_prebuild(fleet, active)
+        with telemetry.span("clock"):
+            units, times, upload_s = latency.round_clock_plan(
+                self._latency_plan(fleet, partner, active, plan), fleet,
+                self.chan, self.workload)
+            if rc.async_rounds:
+                clock, ac = self._advance_clock(state, cohort, units, times,
+                                                upload_s)
+                round_s, wait_s, overlap_s = (ac.round_s, ac.wait_s,
+                                              ac.overlap_s)
+            else:
+                clock, ac = state.clock, None
+                round_s = float(np.max(times)) + upload_s
+                wait_s, overlap_s = latency.barrier_wait_s(times), 0.0
+        with telemetry.span("aggregate"):
+            g, agg = self._aggregate(
+                state, params, fleet, active, ac, rc.aggregation,
+                self._agg_ctx(g_prev, partner, plan.lengths_array(),
+                              eta=rc.lr / self.n))
+        with telemetry.span("broadcast"):
+            params = aggregation.broadcast(g, self.n,
+                                           sharding=self.sharding)
+        with telemetry.span("record"):
+            rec = self._record(state, cohort, plan.pairs, plan.lengths,
+                               mean_loss, round_s, self._engine.cached_steps,
+                               objective=plan.objective, replanned=replanned,
+                               cut_cache=self._cut_cache_status(replanned),
+                               wait_s=wait_s, overlap_s=overlap_s)
+            if rc.overlap_planning:
+                self._overlap_prebuild(fleet, active)
         return rec, params, None, anchor, clock, agg
 
     def _fedpairing_faulted(self, state, fleet, cohort, active, plan,
@@ -1054,22 +1081,24 @@ class RoundDriver:
         """
         rc = self.rc
         fcfg = self.fault_cfg
-        rf = self.fault_model.realize(state.round, active, plan.pairs)
-        # pre-round global snapshot (row 0; all rows equal after the
-        # previous broadcast): with donate=True the engines consume the
-        # input buffers, but a skipped/aborted round must hand back the
-        # pre-round model untouched
-        g_prev = jax.tree_util.tree_map(lambda a: jnp.array(a[0]),
-                                        state.client_params)
-        exec_plan, exec_active = plan, np.asarray(active, bool)
-        if fcfg.mode == "graceful" and rf.dropped:
-            partner2, exec_active = faults.degrade_partner(
-                plan.partner_array(), exec_active, rf, fcfg.orphan)
-            exec_plan = self.round_plan(fleet, partner2, exec_active)
-        partner = exec_plan.partner_array()
-        clock = faults.faulted_clock(
-            self._latency_plan(fleet, partner, exec_active, exec_plan),
-            fleet, self.chan, self.workload, rf, fcfg)
+        with telemetry.span("plan"):
+            rf = self.fault_model.realize(state.round, active, plan.pairs)
+            # pre-round global snapshot (row 0; all rows equal after the
+            # previous broadcast): with donate=True the engines consume the
+            # input buffers, but a skipped/aborted round must hand back the
+            # pre-round model untouched
+            g_prev = jax.tree_util.tree_map(lambda a: jnp.array(a[0]),
+                                            state.client_params)
+            exec_plan, exec_active = plan, np.asarray(active, bool)
+            if fcfg.mode == "graceful" and rf.dropped:
+                partner2, exec_active = faults.degrade_partner(
+                    plan.partner_array(), exec_active, rf, fcfg.orphan)
+                exec_plan = self.round_plan(fleet, partner2, exec_active)
+            partner = exec_plan.partner_array()
+        with telemetry.span("clock"):
+            clock = faults.faulted_clock(
+                self._latency_plan(fleet, partner, exec_active, exec_plan),
+                fleet, self.chan, self.workload, rf, fcfg)
         excluded = sorted(set(rf.dropped) | set(clock.late)
                           | set(clock.link_failed))
         final_active = exec_active.copy()
@@ -1080,9 +1109,10 @@ class RoundDriver:
             # failure -> aborted.  Params roll back to the pre-round
             # global; the batch stream still advances.
             for _ in range(rc.batches_per_round):
-                self.batch_fn()
-            params = aggregation.broadcast(g_prev, self.n,
-                                           sharding=self.sharding)
+                self._next_batch()
+            with telemetry.span("broadcast"):
+                params = aggregation.broadcast(g_prev, self.n,
+                                               sharding=self.sharding)
             status = "aborted" if fcfg.mode == "abort" else "skipped"
             mean_loss = float("nan")
             round_s, wait_s, overlap_s = clock.round_s, 0.0, 0.0
@@ -1090,28 +1120,27 @@ class RoundDriver:
                 # a lost round is a barrier event: the faulted cost is
                 # global (the server waited out the deadline) and every
                 # client resyncs at the publish — nothing to pipeline
-                event_clock, _ = latency.advance_event_clock_barrier(
-                    event_clock, clock.round_s, rc.staleness_bound)
+                with telemetry.span("clock"):
+                    event_clock, _ = latency.advance_event_clock_barrier(
+                        event_clock, clock.round_s, rc.staleness_bound)
         else:
             agg_w = fedpair.pair_weights(fleet.data_sizes, partner)
-            params = state.client_params
-            losses = []
-            for _ in range(rc.batches_per_round):
-                params, l = self._engine.step(params, self.batch_fn(),
-                                              exec_plan, agg_w)
-                losses.append(np.asarray(l))
+            params, losses = self._train(
+                lambda p, b: self._engine.step(p, b, exec_plan, agg_w),
+                state.client_params)
             mean_loss = _mean_active_loss(losses, final_active,
                                           round_idx=state.round)
             if rc.async_rounds:
                 # replay the realized surviving units on the event clock,
                 # capped by the same deadline the sync accounting obeys;
                 # excluded clients resync to the merge (they rejoin fresh)
-                event_clock, ac = self._advance_clock(
-                    state, cohort, clock.units,
-                    np.asarray(clock.times, np.float64), clock.upload_s,
-                    cap_s=(clock.deadline_s
-                           if np.isfinite(clock.deadline_s) else None),
-                    resync=[c for c in excluded if c < self.n])
+                with telemetry.span("clock"):
+                    event_clock, ac = self._advance_clock(
+                        state, cohort, clock.units,
+                        np.asarray(clock.times, np.float64), clock.upload_s,
+                        cap_s=(clock.deadline_s
+                               if np.isfinite(clock.deadline_s) else None),
+                        resync=[c for c in excluded if c < self.n])
                 round_s, wait_s, overlap_s = (ac.round_s, ac.wait_s,
                                               ac.overlap_s)
             else:
@@ -1120,25 +1149,28 @@ class RoundDriver:
             # variate attribution follows the DEGRADED plan and the
             # post-fault survivor mask: an excluded client's variate
             # stays put and never moves c_global (the hard-mask contract)
-            g, agg = self._aggregate(
-                state, params, fleet, final_active, ac, rc.aggregation,
-                self._agg_ctx(
-                    self._agg_snapshot_from(g_prev), partner,
-                    exec_plan.lengths_array(), eta=rc.lr / self.n))
-            params = aggregation.broadcast(g, self.n,
-                                           sharding=self.sharding)
+            with telemetry.span("aggregate"):
+                g, agg = self._aggregate(
+                    state, params, fleet, final_active, ac, rc.aggregation,
+                    self._agg_ctx(
+                        self._agg_snapshot_from(g_prev), partner,
+                        exec_plan.lengths_array(), eta=rc.lr / self.n))
+            with telemetry.span("broadcast"):
+                params = aggregation.broadcast(g, self.n,
+                                               sharding=self.sharding)
             status = "degraded" if excluded else "ok"
-        rec = self._record(state, cohort, exec_plan.pairs,
-                           exec_plan.lengths, mean_loss, round_s,
-                           self._engine.cached_steps,
-                           objective=exec_plan.objective,
-                           replanned=replanned,
-                           cut_cache=self._cut_cache_status(replanned),
-                           status=status, failed=excluded,
-                           retries=rf.retry_total(fcfg.retries),
-                           wait_s=wait_s, overlap_s=overlap_s)
-        if rc.overlap_planning:
-            self._overlap_prebuild(fleet, active)
+        with telemetry.span("record"):
+            rec = self._record(state, cohort, exec_plan.pairs,
+                               exec_plan.lengths, mean_loss, round_s,
+                               self._engine.cached_steps,
+                               objective=exec_plan.objective,
+                               replanned=replanned,
+                               cut_cache=self._cut_cache_status(replanned),
+                               status=status, failed=excluded,
+                               retries=rf.retry_total(fcfg.retries),
+                               wait_s=wait_s, overlap_s=overlap_s)
+            if rc.overlap_planning:
+                self._overlap_prebuild(fleet, active)
         return rec, params, None, anchor, event_clock, agg
 
     def _fl_round(self, state, fleet, cohort, active, pair_seed):
@@ -1147,19 +1179,19 @@ class RoundDriver:
             self._baseline_step = baselines.make_fl_step(self.loss_fn,
                                                          lr=rc.lr)
         g_prev = self._agg_snapshot(state)
-        params = state.client_params
-        losses = []
-        for _ in range(rc.batches_per_round):
-            params, l = self._baseline_step(params, self.batch_fn())
-            losses.append(np.asarray(l))
+        params, losses = self._train(self._baseline_step,
+                                     state.client_params)
         # fl is the degenerate pairing (everyone solo, full stack): the
         # scaffold ownership rule reduces to classic per-client variates
-        g, agg = self._aggregate(
-            state, params, fleet, active, None, "fedavg",
-            self._agg_ctx(g_prev, np.arange(self.n),
-                          np.full(self.n, self.cfg.num_layers),
-                          eta=rc.lr))
-        params = aggregation.broadcast(g, self.n, sharding=self.sharding)
+        with telemetry.span("aggregate"):
+            g, agg = self._aggregate(
+                state, params, fleet, active, None, "fedavg",
+                self._agg_ctx(g_prev, np.arange(self.n),
+                              np.full(self.n, self.cfg.num_layers),
+                              eta=rc.lr))
+        with telemetry.span("broadcast"):
+            params = aggregation.broadcast(g, self.n,
+                                           sharding=self.sharding)
         plan = planning.baseline_plan(self.n, self.cfg.num_layers,
                                       active=active,
                                       server_cut=rc.server_cut,
@@ -1187,13 +1219,15 @@ class RoundDriver:
             self._baseline_step = baselines.make_sl_step(
                 self.loss_fn, split, self.cfg.num_layers, cut, rc.lr)
         client, server = state.client_params, state.server_params
-        batches = [self.batch_fn() for _ in range(rc.batches_per_round)]
+        batches = [self._next_batch() for _ in range(rc.batches_per_round)]
         losses = []
         for i in cohort:                 # sequential client relay
             for b in batches:
                 mine = jax.tree_util.tree_map(lambda a: a[int(i)], b)
-                client, server, l = self._baseline_step(client, server, mine)
-                losses.append(float(l))
+                with telemetry.span("step"):
+                    client, server, l = self._baseline_step(client, server,
+                                                            mine)
+                losses.append(float(_host_loss(l)))
         sub = latency.subfleet(fleet, cohort)
         round_s = latency.round_time_vanilla_sl(
             sub, self.chan, self.workload, client_layers=cut,
@@ -1222,16 +1256,19 @@ class RoundDriver:
         sub_params = jax.tree_util.tree_map(lambda a: a[idx], client)
         losses = []
         for _ in range(rc.batches_per_round):
-            b = self.batch_fn()
+            b = self._next_batch()
             sub_b = jax.tree_util.tree_map(lambda a: a[idx], b)
-            sub_params, server, l = self._baseline_step(sub_params, server,
-                                                        sub_b)
-            losses.append(np.asarray(l))
+            with telemetry.span("step"):
+                sub_params, server, l = self._baseline_step(sub_params,
+                                                            server, sub_b)
+            losses.append(_host_loss(l))
         # round end: FedAvg the cohort's bottoms, broadcast to everyone
-        sub_w = jnp.asarray(fleet.data_sizes[idx], jnp.float32)
-        g = aggregation.aggregate(sub_params, sub_w, "fedavg",
-                                  round_idx=state.round)
-        client = aggregation.broadcast(g, self.n)
+        with telemetry.span("aggregate"):
+            sub_w = jnp.asarray(fleet.data_sizes[idx], jnp.float32)
+            g = aggregation.aggregate(sub_params, sub_w, "fedavg",
+                                      round_idx=state.round)
+        with telemetry.span("broadcast"):
+            client = aggregation.broadcast(g, self.n)
         sub = latency.subfleet(fleet, cohort)
         sub_cycles = (self._cycles[cohort] if self._cycles is not None
                       else None)
@@ -1353,6 +1390,13 @@ class NonFiniteLossError(RuntimeError):
             f"model diverged; lower the learning rate or inspect the "
             f"round's batches (fault handling only masks availability "
             f"failures, never numerical ones)")
+
+
+def _host_loss(loss) -> np.ndarray:
+    """One step's loss copied to the host, which waits for the device."""
+    with telemetry.span("loss_sync"):
+        telemetry.count("host_sync")
+        return np.asarray(loss)
 
 
 def _mean_active_loss(losses: Sequence[np.ndarray], active: np.ndarray,

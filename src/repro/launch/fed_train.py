@@ -33,6 +33,8 @@ import argparse
 import time
 from typing import Optional
 
+import jax
+
 from repro.configs import ArchConfig
 from repro.core import aggregation, latency, planning, rounds
 from repro.core.latency import ChannelModel
@@ -168,8 +170,10 @@ def main() -> None:
               f"device(s)")
     state = fault_cli.initial_state(driver, args)
     for _ in range(max(0, args.rounds - state.round)):
-        t0 = time.time()
+        t0 = time.perf_counter()
         state = driver.run_round(state)
+        jax.block_until_ready(state.client_params)
+        wall = time.perf_counter() - t0
         r = state.history[-1]
         cache_note = "" if r.cut_cache == "n/a" \
             else f", cut cache {r.cut_cache}"
@@ -181,7 +185,7 @@ def main() -> None:
               f"sim {r.sim_round_s:.1f}s "
               f"({r.cached_steps} compiled steps, "
               f"{'replanned' if r.replanned else 'kept plan'}"
-              f"{cache_note}{fault_note}, {time.time() - t0:.1f}s wall)")
+              f"{cache_note}{fault_note}, {wall:.1f}s wall)")
         fault_cli.maybe_checkpoint(driver, state, args)
     fault_cli.maybe_checkpoint(driver, state, args, final=True)
     print(f"[fed] total simulated wall-clock: {state.sim_time_s:.1f}s")

@@ -19,6 +19,8 @@ import dataclasses
 import json
 import time
 
+import jax
+
 from repro.core import aggregation, latency, rounds
 from repro.core.latency import ChannelModel
 from repro.launch import compile_cache, fault_cli, fleet_cli
@@ -141,8 +143,10 @@ def run_sim(args) -> rounds.RoundState:
           f"{shard_note}")
     state = fault_cli.initial_state(driver, args)
     for _ in range(max(0, args.rounds - state.round)):
-        t0 = time.time()
+        t0 = time.perf_counter()
         state = driver.run_round(state)
+        jax.block_until_ready(state.client_params)
+        wall = time.perf_counter() - t0
         r = state.history[-1]
         cache_note = "" if r.cut_cache == "n/a" \
             else f", cut cache {r.cut_cache}"
@@ -153,7 +157,7 @@ def run_sim(args) -> rounds.RoundState:
               f"sim={r.sim_round_s:.1f}s (total {r.sim_total_s:.1f}s, "
               f"{r.cached_steps} compiled steps, "
               f"{'replanned' if r.replanned else 'kept plan'}"
-              f"{cache_note}{fault_note}, {time.time()-t0:.1f}s wall)")
+              f"{cache_note}{fault_note}, {wall:.1f}s wall)")
         fault_cli.maybe_checkpoint(driver, state, args)
     fault_cli.maybe_checkpoint(driver, state, args, final=True)
     print(f"[sim] simulated wall-clock for {args.rounds} rounds: "
