@@ -25,7 +25,7 @@ HOW the cohort's replicas become the next global model:
 
 Mesh-awareness (DESIGN.md §11): both reductions run over the leading
 client axis, so when the replicas arrive sharded over the fleet mesh
-(``sharding.fleet.FleetSharding``) XLA lowers the mean / tensordot into
+(``sharding.fleet.FleetSharding``) XLA lowers the mean / weighted sum into
 per-shard partial sums plus the cross-device psum-style combine — no
 separate collective code path, and the zero-weight hard-mask below is
 applied per shard BEFORE the combine, so an excluded replica's values are
@@ -38,6 +38,7 @@ rule (``init_state(sharding=...)``).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Optional, Tuple
 
 import jax
@@ -65,55 +66,81 @@ class EmptyCohortError(ValueError):
             f"skip the round instead")
 
 
-def _unnormalized_weights(agg_w: jnp.ndarray, mode: str,
-                          active: Optional[jnp.ndarray],
-                          staleness: Optional[jnp.ndarray]
-                          ) -> Optional[jnp.ndarray]:
-    """The (N,) pre-normalization weight vector, or ``None`` for the
-    plain-mean fast path (mode "paper", no mask, no staleness) — the one
-    place the mode × mask × staleness composition is defined, shared by
-    ``aggregate`` and ``aggregation_weights``."""
-    if staleness is not None:
+def _host_inputs(*xs) -> Tuple[Optional[np.ndarray], ...]:
+    """NumPy copies of the weight inputs.  The driver passes NumPy, which
+    costs nothing; a device array among them (direct callers, tests) is
+    read in one blocking ``device_get``, counted as one ``host_sync``."""
+    if any(isinstance(x, jax.Array) for x in xs):
         telemetry.count("host_sync")
-        if not bool(jnp.any(staleness)):
-            staleness = None        # all fresh: keep the synchronous jaxpr
+        xs = jax.device_get(xs)
+    return tuple(None if x is None else np.asarray(x) for x in xs)
+
+
+def _unnormalized_weights(agg_w, mode: str, active, staleness
+                          ) -> Optional[np.ndarray]:
+    """The (N,) float32 pre-normalization weight vector, on the host, or
+    ``None`` for the plain-mean fast path (mode "paper", no mask, no
+    staleness) — the one place the mode × mask × staleness composition
+    is defined, shared by ``aggregate`` and ``aggregation_weights``."""
+    if mode not in ("paper", "fedavg"):
+        raise ValueError(f"unknown aggregation mode {mode!r}")
+    agg_w, active, staleness = _host_inputs(
+        agg_w if mode == "fedavg" else None, active, staleness)
+    if staleness is not None and not staleness.any():
+        staleness = None            # all fresh: keep the synchronous path
     if mode == "paper":
         if active is None and staleness is None:
             return None
-        if active is None:
-            w = jnp.ones_like(jnp.asarray(staleness, jnp.float32))
-        else:
-            w = jnp.asarray(active, jnp.float32)
-    elif mode == "fedavg":
-        w = jnp.asarray(agg_w, jnp.float32)
-        if active is not None:
-            w = w * jnp.asarray(active, jnp.float32)
+        w = (np.ones(staleness.shape, np.float32) if active is None
+             else active.astype(np.float32))
     else:
-        raise ValueError(f"unknown aggregation mode {mode!r}")
+        w = agg_w.astype(np.float32)
+        if active is not None:
+            w = w * active.astype(np.float32)
     if staleness is not None:
-        w = w / (1.0 + jnp.asarray(staleness, jnp.float32))
+        w = w / (np.float32(1.0) + staleness.astype(np.float32))
     return w
 
 
+def _normalized(w: np.ndarray, round_idx: Optional[int]) -> np.ndarray:
+    """``w / Σw`` in float32, or ``EmptyCohortError`` when the weights sum
+    to zero — decided from host values, with no device read."""
+    total = w.sum(dtype=np.float32)
+    if float(total) <= 0.0:
+        raise EmptyCohortError(round_idx)
+    return w / total
+
+
+@jax.jit
 def _masked_weighted_mean(w: jnp.ndarray, tree: Dict) -> Dict:
-    """``Σ_i w_i · tree[i]`` over the leading client axis with the
-    zero-weight hard-mask: 0 * nan is nan, and an EXCLUDED client's
+    """``Σ_i w_i · tree[i]`` over the leading client axis, the whole tree
+    in one program; ``w`` is a traced (N,) input, so one compile serves
+    every mask, mode and staleness pattern of a tree.
+
+    The zero-weight hard-mask: 0 * nan is nan, and an EXCLUDED client's
     values may legitimately be garbage (a late straggler that diverged) —
-    exclusion must mean its values are never read.  Bit-identical when
-    every weight is positive (jnp.where selects a unchanged)."""
+    exclusion must mean its values are never read.  The sum is an explicit
+    multiply and a reduce over the client axis in the leaf's dtype (full
+    float32 for float32 replicas), never a default-precision dot."""
 
     def wmean(a):
-        keep = (w > 0).reshape((-1,) + (1,) * (a.ndim - 1))
-        masked = jnp.where(keep, a, jnp.zeros((), a.dtype))
-        return jnp.tensordot(w.astype(a.dtype), masked, axes=(0, 0))
+        shape = (-1,) + (1,) * (a.ndim - 1)
+        keep = (w > 0).reshape(shape)
+        term = w.astype(a.dtype).reshape(shape) * a
+        return jnp.sum(jnp.where(keep, term, jnp.zeros((), a.dtype)), axis=0)
 
     return jax.tree_util.tree_map(wmean, tree)
 
 
-def aggregate(client_params: Dict, agg_w: jnp.ndarray,
-              mode: str = "paper",
-              active: Optional[jnp.ndarray] = None,
-              staleness: Optional[jnp.ndarray] = None,
+@jax.jit
+def _plain_mean(tree: Dict) -> Dict:
+    """The unweighted mean over the client axis, the whole tree in one
+    program (``jnp.mean``, so the plain-mean bits are unchanged)."""
+    return jax.tree_util.tree_map(lambda a: jnp.mean(a, axis=0), tree)
+
+
+def aggregate(client_params: Dict, agg_w, mode: str = "paper",
+              active=None, staleness=None,
               round_idx: Optional[int] = None) -> Dict:
     """client_params stacked (N, ...) -> global params.
 
@@ -133,22 +160,23 @@ def aggregate(client_params: Dict, agg_w: jnp.ndarray,
     ``active`` and the zero-weight hard-mask; ``None`` (the synchronous
     path) or an all-zero vector (async at staleness bound 0) leaves
     every weight untouched, preserving the §12 bit-identity contract.
+
+    The weights are composed on the host (NumPy ``agg_w`` / ``active`` /
+    ``staleness`` cost no device read) and the reduction is one jitted
+    program over the whole tree.
     """
     w = _unnormalized_weights(agg_w, mode, active, staleness)
     if w is None:
-        return jax.tree_util.tree_map(lambda a: jnp.mean(a, axis=0),
-                                      client_params)
-    total = jnp.sum(w)
-    telemetry.count("host_sync")
-    if float(total) <= 0.0:
-        raise EmptyCohortError(round_idx)
-    return _masked_weighted_mean(w / total, client_params)
+        telemetry.count("agg_program")
+        return _plain_mean(client_params)
+    w = _normalized(w, round_idx)
+    telemetry.count("agg_program")
+    return _masked_weighted_mean(w, client_params)
 
 
-def aggregation_weights(agg_w: jnp.ndarray, n: int, mode: str = "paper",
-                        active: Optional[jnp.ndarray] = None,
-                        staleness: Optional[jnp.ndarray] = None,
-                        round_idx: Optional[int] = None) -> jnp.ndarray:
+def aggregation_weights(agg_w, n: int, mode: str = "paper",
+                        active=None, staleness=None,
+                        round_idx: Optional[int] = None) -> np.ndarray:
     """The normalized (N,) per-client weight vector ``aggregate`` reduces
     with — what the variance-reduced policies consume to keep their
     correction term on EXACTLY the weights of the base step (cohort mask,
@@ -156,22 +184,44 @@ def aggregation_weights(agg_w: jnp.ndarray, n: int, mode: str = "paper",
     fast path normalizes to the uniform 1/N vector."""
     w = _unnormalized_weights(agg_w, mode, active, staleness)
     if w is None:
-        return jnp.full((n,), 1.0 / n, jnp.float32)
-    total = jnp.sum(w)
-    telemetry.count("host_sync")
-    if float(total) <= 0.0:
-        raise EmptyCohortError(round_idx)
-    return w / total
+        return np.full((n,), 1.0 / n, np.float32)
+    return _normalized(w, round_idx)
 
 
-def broadcast(global_params: Dict, n: int, sharding=None) -> Dict:
-    """Global params -> N stacked client replicas.  With a
-    ``FleetSharding`` the replicas are placed straight onto the client
-    placement (leading dim over the mesh's fleet axis) instead of
-    materializing an unsharded (N, ...) tree first."""
-    out = jax.tree_util.tree_map(
+def _replicas(global_params: Dict, n: int, into=None) -> Dict:
+    del into            # donated: its buffers receive the replicas
+    return jax.tree_util.tree_map(
         lambda a: jnp.broadcast_to(a[None], (n,) + a.shape), global_params)
-    return out if sharding is None else sharding.place(out)
+
+
+@functools.lru_cache(maxsize=16)
+def _broadcast_program(out_sharding, donate: bool):
+    """The broadcast program for one client placement (``None``: the
+    default device), writing into donated replicas or into new ones.
+    ``keep_unused`` keeps the donated tree a parameter of the program, so
+    XLA aliases it to the output instead of pruning it."""
+    placed = {} if out_sharding is None else {"out_shardings": out_sharding}
+    return jax.jit(_replicas, static_argnums=1, keep_unused=True,
+                   donate_argnums=(2,) if donate else (), **placed)
+
+
+def broadcast(global_params: Dict, n: int, sharding=None,
+              into: Optional[Dict] = None) -> Dict:
+    """Global params -> N stacked client replicas, one jitted program over
+    the whole tree.  With a ``FleetSharding`` the program's outputs are
+    the client placement (leading dim over the mesh's fleet axis), so no
+    unsharded (N, ...) tree is materialized and nothing is re-placed.
+
+    ``into`` is the stacked (N, ...) tree the new replicas replace, when
+    the caller has no further use for it (the round's trained replicas,
+    once aggregated): it is donated and the replicas are written into its
+    buffers, so the device holds one set of replicas across the round
+    boundary instead of allocating a second set beside it."""
+    out_sharding = None if sharding is None else sharding.client_sharding(
+        jax.ShapeDtypeStruct((n,), jnp.float32))
+    program = _broadcast_program(out_sharding, into is not None)
+    telemetry.count("agg_program")
+    return program(global_params, n, into)
 
 
 # ---------------------------------------------------------------------------
@@ -333,6 +383,7 @@ class ScaffoldAggregation(AggregationPolicy):
                                 staleness=staleness, round_idx=round_idx)
         k_eta = float(ctx.steps) * float(ctx.lr)
         if state.applied:
+            telemetry.count("agg_program")
             cbar = _masked_weighted_mean(w, state.c_local)
             g = jax.tree_util.tree_map(
                 lambda x, cb, cg: x + jnp.asarray(k_eta, x.dtype)
@@ -340,11 +391,8 @@ class ScaffoldAggregation(AggregationPolicy):
                 g, cbar, state.c_global)
 
         # -- variate refresh over the trained cohort ----------------------
-        if active is None:
-            act = np.ones(n, bool)
-        else:
-            telemetry.count("host_sync")
-            act = np.asarray(active, bool)
+        (act,) = _host_inputs(active)
+        act = np.ones(n, bool) if act is None else act.astype(bool)
         partner = np.asarray(ctx.partner, np.int64)
         lengths = np.asarray(ctx.lengths, np.float64)
         W = float(ctx.num_layers)

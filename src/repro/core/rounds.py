@@ -972,7 +972,7 @@ class RoundDriver:
         discount."""
         if ac is None or not any(ac.staleness):
             return None
-        return jnp.asarray(ac.staleness, jnp.int32)
+        return np.asarray(ac.staleness, np.int32)
 
     # -- aggregation-policy plumbing (DESIGN.md §13) ----------------------
 
@@ -1011,10 +1011,12 @@ class RoundDriver:
                    mode: str, ctx) -> Tuple[Dict, object]:
         """One policy aggregation with the driver's standard arguments
         (cohort mask, staleness discount, round index for the
-        EmptyCohortError)."""
+        EmptyCohortError), all host NumPy: the weights are composed and
+        the empty cohort refused on the host, with no device read."""
         return self.agg_policy.apply(
-            params, jnp.asarray(fleet.data_sizes, jnp.float32), mode,
-            active=jnp.asarray(active), staleness=self._staleness_arg(ac),
+            params, np.asarray(fleet.data_sizes, np.float32), mode,
+            active=np.asarray(active, bool),
+            staleness=self._staleness_arg(ac),
             state=state.agg, ctx=ctx, round_idx=state.round)
 
     def _fedpairing_round(self, state, fleet, cohort, active, pair_seed):
@@ -1054,8 +1056,8 @@ class RoundDriver:
                 self._agg_ctx(g_prev, partner, plan.lengths_array(),
                               eta=rc.lr / self.n))
         with telemetry.span("broadcast"):
-            params = aggregation.broadcast(g, self.n,
-                                           sharding=self.sharding)
+            params = aggregation.broadcast(g, self.n, sharding=self.sharding,
+                                           into=params)
         with telemetry.span("record"):
             rec = self._record(state, cohort, plan.pairs, plan.lengths,
                                mean_loss, round_s, self._engine.cached_steps,
@@ -1157,7 +1159,8 @@ class RoundDriver:
                         exec_plan.lengths_array(), eta=rc.lr / self.n))
             with telemetry.span("broadcast"):
                 params = aggregation.broadcast(g, self.n,
-                                               sharding=self.sharding)
+                                               sharding=self.sharding,
+                                               into=params)
             status = "degraded" if excluded else "ok"
         with telemetry.span("record"):
             rec = self._record(state, cohort, exec_plan.pairs,
@@ -1190,8 +1193,8 @@ class RoundDriver:
                               np.full(self.n, self.cfg.num_layers),
                               eta=rc.lr))
         with telemetry.span("broadcast"):
-            params = aggregation.broadcast(g, self.n,
-                                           sharding=self.sharding)
+            params = aggregation.broadcast(g, self.n, sharding=self.sharding,
+                                           into=params)
         plan = planning.baseline_plan(self.n, self.cfg.num_layers,
                                       active=active,
                                       server_cut=rc.server_cut,
@@ -1264,7 +1267,7 @@ class RoundDriver:
             losses.append(_host_loss(l))
         # round end: FedAvg the cohort's bottoms, broadcast to everyone
         with telemetry.span("aggregate"):
-            sub_w = jnp.asarray(fleet.data_sizes[idx], jnp.float32)
+            sub_w = np.asarray(fleet.data_sizes[idx], np.float32)
             g = aggregation.aggregate(sub_params, sub_w, "fedavg",
                                       round_idx=state.round)
         with telemetry.span("broadcast"):

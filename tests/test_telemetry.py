@@ -116,11 +116,16 @@ def test_fedpairing_round_spans_and_host_syncs(on):
             assert sorted(s.name for s in spans
                           if _parent(s, spans) is step) == [
                 "step.args", "step.dispatch"]
-        # one read per local step's loss, and the aggregation's check
-        # that the cohort's weights do not sum to zero
-        syncs = [_parent(c, spans).name for c in _spans("count")
-                 if c.name == "host_sync" and r.start <= c.start <= r.end]
-        assert sorted(syncs) == ["aggregate", "loss_sync", "loss_sync"]
+        # one read per local step's loss; the aggregation composes its
+        # weights and checks the cohort on the host, so it reads nothing
+        in_round = [c for c in _spans("count") if r.start <= c.start <= r.end]
+        syncs = [_parent(c, spans).name for c in in_round
+                 if c.name == "host_sync"]
+        assert sorted(syncs) == ["loss_sync", "loss_sync"]
+        # one jitted reduce in `aggregate`, one jitted broadcast
+        programs = [_parent(c, spans).name for c in in_round
+                    if c.name == "agg_program"]
+        assert sorted(programs) == ["aggregate", "broadcast"]
 
 
 @pytest.mark.parametrize("algorithm,names", [
