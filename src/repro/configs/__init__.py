@@ -13,6 +13,7 @@ from repro.configs.base import (  # noqa: F401  (re-exported)
     ArchFamily,
     AttentionKind,
     InputShape,
+    NormKind,
     INPUT_SHAPES,
     TRAIN_4K,
     PREFILL_32K,

@@ -24,6 +24,11 @@ class ArchFamily(str, enum.Enum):
     AUDIO = "audio"        # seamless: encoder-decoder, stubbed codec frontend
 
 
+class NormKind(str, enum.Enum):
+    RMS = "rms"                    # RMSNorm, scale only
+    LAYER = "layer"                # LayerNorm, scale and bias
+
+
 class AttentionKind(str, enum.Enum):
     FULL = "full"                  # causal full attention
     SLIDING = "sliding"            # causal sliding-window attention
@@ -56,6 +61,7 @@ class ArchConfig:
     sliding_window: int = 0             # used when attention == SLIDING
     qkv_bias: bool = False              # qwen-style QKV bias
     rope_theta: float = 10000.0
+    partial_rotary_factor: float = 1.0  # share of each head rope rotates
     mrope_sections: Tuple[int, int, int] = ()  # qwen2-vl M-RoPE (t, h, w) splits
     # --- MoE ---
     num_experts: int = 0
@@ -77,6 +83,7 @@ class ArchConfig:
     # --- modality frontend stubs ---
     frontend_tokens: int = 0            # patch/frame embeddings prepended (stub)
     # --- misc ---
+    norm: NormKind = NormKind.RMS
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     dtype: str = "bfloat16"             # activation/compute dtype
@@ -92,6 +99,11 @@ class ArchConfig:
         if self.num_heads:
             return self.d_model // self.num_heads
         return 0
+
+    @property
+    def rotary_dim(self) -> int:
+        """Leading dimensions of each q/k head that rope rotates."""
+        return int(self.resolved_head_dim * self.partial_rotary_factor)
 
     @property
     def padded_vocab(self) -> int:

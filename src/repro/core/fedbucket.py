@@ -49,7 +49,7 @@ import numpy as np
 from repro.configs.base import ArchConfig, ArchFamily
 from repro.core import planning
 from repro.kernels.ref import ce_chunk_size
-from repro.models import common, rwkv6, transformer
+from repro.models import rwkv6, transformer
 
 BUCKET_FAMILIES = (ArchFamily.DENSE, ArchFamily.MOE, ArchFamily.SSM)
 
@@ -293,8 +293,7 @@ def make_bucketed_fed_step(cfg: ArchConfig, partner, lengths, agg_w,
         tokens, labels = batch["tokens"], batch["labels"]
         S = tokens.shape[-1]
         pos = jnp.arange(S)[None, :]
-        cos, sin = common.rope_cos_sin(pos, max(cfg.resolved_head_dim, 2),
-                                       cfg.rope_theta)
+        cos, sin = transformer.rope_tables(cfg, pos)
 
         x = jax.vmap(lambda p, t: transformer.embed(p, t, cfg))(
             client_params, tokens)

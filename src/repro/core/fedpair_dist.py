@@ -48,7 +48,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core import fedbucket
-from repro.models import common, transformer
+from repro.models import transformer
 
 # shared flow pieces live in fedbucket (the bucketing engine); these
 # aliases keep the historical private names importable.
@@ -126,8 +126,7 @@ def make_dist_fed_step(cfg: ArchConfig, mesh, perm_pairs: Sequence[Tuple[int, in
         x = transformer.embed(own, tokens, cfg)
         S = tokens.shape[1]
         pos = jnp.arange(S)[None, :]
-        cos, sin = common.rope_cos_sin(pos, max(cfg.resolved_head_dim, 2),
-                                       cfg.rope_theta)
+        cos, sin = transformer.rope_tables(cfg, pos)
 
         bottom = (own["blocks"] if bot_hi == W else
                   jax.tree_util.tree_map(lambda a: a[:bot_hi], own["blocks"]))

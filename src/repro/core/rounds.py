@@ -1009,15 +1009,18 @@ class RoundDriver:
 
     def _aggregate(self, state: RoundState, params, fleet, active, ac,
                    mode: str, ctx) -> Tuple[Dict, object]:
-        """One policy aggregation with the driver's standard arguments
-        (cohort mask, staleness discount, round index for the
+        """One policy aggregation and broadcast with the driver's standard
+        arguments (cohort mask, staleness discount, round index for the
         EmptyCohortError), all host NumPy: the weights are composed and
-        the empty cohort refused on the host, with no device read."""
+        the empty cohort refused on the host, with no device read.  The
+        trained ``params`` are donated and the next round's replicas come
+        back written into them."""
         return self.agg_policy.apply(
             params, np.asarray(fleet.data_sizes, np.float32), mode,
             active=np.asarray(active, bool),
             staleness=self._staleness_arg(ac),
-            state=state.agg, ctx=ctx, round_idx=state.round)
+            state=state.agg, ctx=ctx, round_idx=state.round,
+            into_replicas=True, sharding=self.sharding)
 
     def _fedpairing_round(self, state, fleet, cohort, active, pair_seed):
         rc = self.rc
@@ -1051,13 +1054,10 @@ class RoundDriver:
                 round_s = float(np.max(times)) + upload_s
                 wait_s, overlap_s = latency.barrier_wait_s(times), 0.0
         with telemetry.span("aggregate"):
-            g, agg = self._aggregate(
+            params, agg = self._aggregate(
                 state, params, fleet, active, ac, rc.aggregation,
                 self._agg_ctx(g_prev, partner, plan.lengths_array(),
                               eta=rc.lr / self.n))
-        with telemetry.span("broadcast"):
-            params = aggregation.broadcast(g, self.n, sharding=self.sharding,
-                                           into=params)
         with telemetry.span("record"):
             rec = self._record(state, cohort, plan.pairs, plan.lengths,
                                mean_loss, round_s, self._engine.cached_steps,
@@ -1152,15 +1152,11 @@ class RoundDriver:
             # post-fault survivor mask: an excluded client's variate
             # stays put and never moves c_global (the hard-mask contract)
             with telemetry.span("aggregate"):
-                g, agg = self._aggregate(
+                params, agg = self._aggregate(
                     state, params, fleet, final_active, ac, rc.aggregation,
                     self._agg_ctx(
                         self._agg_snapshot_from(g_prev), partner,
                         exec_plan.lengths_array(), eta=rc.lr / self.n))
-            with telemetry.span("broadcast"):
-                params = aggregation.broadcast(g, self.n,
-                                               sharding=self.sharding,
-                                               into=params)
             status = "degraded" if excluded else "ok"
         with telemetry.span("record"):
             rec = self._record(state, cohort, exec_plan.pairs,
@@ -1187,14 +1183,11 @@ class RoundDriver:
         # fl is the degenerate pairing (everyone solo, full stack): the
         # scaffold ownership rule reduces to classic per-client variates
         with telemetry.span("aggregate"):
-            g, agg = self._aggregate(
+            params, agg = self._aggregate(
                 state, params, fleet, active, None, "fedavg",
                 self._agg_ctx(g_prev, np.arange(self.n),
                               np.full(self.n, self.cfg.num_layers),
                               eta=rc.lr))
-        with telemetry.span("broadcast"):
-            params = aggregation.broadcast(g, self.n, sharding=self.sharding,
-                                           into=params)
         plan = planning.baseline_plan(self.n, self.cfg.num_layers,
                                       active=active,
                                       server_cut=rc.server_cut,

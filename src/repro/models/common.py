@@ -61,6 +61,19 @@ def rms_norm_init(n: Optional[int], d: int, dtype=jnp.float32):
     return jnp.ones(shape, dtype)
 
 
+def layer_norm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
+               eps: float = 1e-5) -> jnp.ndarray:
+    """LayerNorm (biased variance) in fp32 accumulation, cast back to input
+    dtype."""
+    dtype = x.dtype
+    xf = x.astype(jnp.float32)
+    xc = xf - jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xc), axis=-1, keepdims=True)
+    out = xc * jax.lax.rsqrt(var + eps)
+    return (out * scale.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(dtype)
+
+
 # ---------------------------------------------------------------------------
 # rotary position embeddings (standard + M-RoPE)
 # ---------------------------------------------------------------------------
@@ -81,9 +94,15 @@ def rope_cos_sin(positions: jnp.ndarray, head_dim: int, theta: float
 def apply_rope(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
     """Apply rotary embedding.
 
-    ``x``: (..., S, H, D); ``cos``/``sin``: broadcastable to (..., S, 1, D/2).
-    Uses the paired-halves convention (llama): rotate (x1, x2) of split halves.
+    ``x``: (..., S, H, D); ``cos``/``sin``: broadcastable to (..., S, 1, R/2).
+    Uses the paired-halves convention (llama): rotate (x1, x2) of split
+    halves of the first R dimensions; with R < D (partial rotary) the other
+    D - R dimensions pass through unchanged.
     """
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate([apply_rope(x[..., :rot], cos, sin),
+                                x[..., rot:]], axis=-1)
     d_half = x.shape[-1] // 2
     x1, x2 = x[..., :d_half], x[..., d_half:]
     cos = cos.astype(x.dtype)

@@ -35,7 +35,7 @@ def encode(params: Dict, frames: jnp.ndarray, cfg: ArchConfig, *,
     frames = frames.astype(jnp.dtype(cfg.dtype))
     S = frames.shape[1]
     pos = jnp.arange(S)[None, :]
-    cos, sin = common.rope_cos_sin(pos, cfg.resolved_head_dim, cfg.rope_theta)
+    cos, sin = transformer.rope_tables(cfg, pos)
     x, _ = transformer.stack_apply(params["encoder"], frames, cos, sin, cfg,
                                    n_layers=cfg.num_encoder_layers,
                                    causal=False, remat=remat,
@@ -65,7 +65,7 @@ def decode_train(params: Dict, tokens: jnp.ndarray, enc_out: jnp.ndarray,
     x = transformer.embed(params, tokens, cfg)
     S = tokens.shape[1]
     pos = jnp.arange(S)[None, :]
-    cos, sin = common.rope_cos_sin(pos, cfg.resolved_head_dim, cfg.rope_theta)
+    cos, sin = transformer.rope_tables(cfg, pos)
     ekv = stacked_cross_kv(params, enc_out, cfg)
     x, _ = transformer.stack_apply(params["decoder"], x, cos, sin, cfg,
                                    gates=gates, enc_kv_stacked=ekv,
@@ -111,7 +111,7 @@ def decode_step(params: Dict, tokens: jnp.ndarray, state: Dict,
     x = transformer.embed(params, tokens, cfg)
     index = state["index"]
     pos = jnp.full((1, 1), index, jnp.int32)
-    cos, sin = common.rope_cos_sin(pos, cfg.resolved_head_dim, cfg.rope_theta)
+    cos, sin = transformer.rope_tables(cfg, pos)
     x, kv = transformer.decode_stack_apply(
         params["decoder"], x, cos, sin, state["kv"], index, spec, cfg,
         enc_kv_stacked=(state["cross_k"], state["cross_v"]), unroll=unroll)

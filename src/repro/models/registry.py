@@ -62,8 +62,7 @@ def _positions_cos_sin(cfg: ArchConfig, batch: Dict, S: int):
     if cfg.family == ArchFamily.VLM:
         return common.mrope_cos_sin(batch["positions"], hd, cfg.rope_theta,
                                     cfg.mrope_sections)
-    pos = jnp.arange(S)[None, :]
-    return common.rope_cos_sin(pos, hd, cfg.rope_theta)
+    return transformer.rope_tables(cfg, jnp.arange(S)[None, :])
 
 
 def forward_hidden(params: Dict, batch: Dict, cfg: ArchConfig,
@@ -130,16 +129,17 @@ def forward_logits(params: Dict, batch: Dict, cfg: ArchConfig,
 def _ce_terms(logits: jnp.ndarray, labels: jnp.ndarray, vocab: int
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(sum token loss, valid count); labels < 0 masked; padded vocab cut."""
-    logits = logits.astype(jnp.float32)
-    if vocab < logits.shape[-1]:
-        pad = jnp.full(logits.shape[:-1] + (logits.shape[-1] - vocab,), -1e30,
-                       logits.dtype)
-        logits = jnp.concatenate([logits[..., :vocab], pad], axis=-1)
-    valid = labels >= 0
-    lbl = jnp.maximum(labels, 0)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, lbl[..., None], axis=-1)[..., 0]
-    return jnp.sum((logz - gold) * valid), jnp.sum(valid)
+    with jax.named_scope("lm_head"):
+        logits = logits.astype(jnp.float32)
+        if vocab < logits.shape[-1]:
+            pad = jnp.full(logits.shape[:-1] + (logits.shape[-1] - vocab,),
+                           -1e30, logits.dtype)
+            logits = jnp.concatenate([logits[..., :vocab], pad], axis=-1)
+        valid = labels >= 0
+        lbl = jnp.maximum(labels, 0)
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, lbl[..., None], axis=-1)[..., 0]
+        return jnp.sum((logz - gold) * valid), jnp.sum(valid)
 
 
 def loss_fn(params: Dict, batch: Dict, cfg: ArchConfig,
@@ -261,8 +261,8 @@ def serve_step(params: Dict, tokens: jnp.ndarray, state: Dict,
         cos, sin = common.mrope_cos_sin(mrope_positions, hd, cfg.rope_theta,
                                         cfg.mrope_sections)
     else:
-        pos = jnp.full((1, 1), index, jnp.int32)
-        cos, sin = common.rope_cos_sin(pos, hd, cfg.rope_theta)
+        cos, sin = transformer.rope_tables(
+            cfg, jnp.full((1, 1), index, jnp.int32))
     x, kv = transformer.decode_stack_apply(params["blocks"], x, cos, sin,
                                            state["kv"], index, spec, cfg,
                                            unroll=unroll, sp_decode=sp_decode)

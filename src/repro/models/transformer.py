@@ -14,9 +14,55 @@ from typing import Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ArchConfig, ArchFamily, AttentionKind
+from repro.configs.base import ArchConfig, ArchFamily, AttentionKind, NormKind
 from repro.models import attention as attn
 from repro.models import common, moe as moe_lib
+
+
+# ---------------------------------------------------------------------------
+# the configuration's norm and rope tables (every dense-block path)
+# ---------------------------------------------------------------------------
+
+def norm_init(cfg: ArchConfig, n: Optional[int], dtype=jnp.float32):
+    """An RMSNorm's scale (ones); a LayerNorm's scale (ones) and bias
+    (zeros) as one leaf, ``norm_leaf``: (2, d), or (n, 2, d) over n
+    layers.  One leaf per norm keeps every norm a single named leaf of
+    the tree, whichever the configuration's kind."""
+    scale = common.rms_norm_init(n, cfg.d_model, dtype)
+    if cfg.norm == NormKind.LAYER:
+        return norm_leaf(scale, jnp.zeros_like(scale))
+    return scale
+
+
+def norm_leaf(scale: jnp.ndarray, bias: jnp.ndarray) -> jnp.ndarray:
+    """A LayerNorm's leaf from its scale and bias, ``(..., d)`` each: the
+    two stacked on axis -2."""
+    return jnp.stack([scale, bias], axis=-2)
+
+
+def norm_parts(w: jnp.ndarray, cfg: ArchConfig
+               ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
+    """(scale, bias) of a norm leaf; an RMSNorm has no bias (None)."""
+    if cfg.norm == NormKind.LAYER:
+        return w[..., 0, :], w[..., 1, :]
+    return w, None
+
+
+def norm(x: jnp.ndarray, w: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
+    """The configuration's norm: RMSNorm, or LayerNorm with a bias."""
+    with jax.named_scope("norm"):
+        scale, bias = norm_parts(w, cfg)
+        if bias is None:
+            return common.rms_norm(x, scale, cfg.norm_eps)
+        return common.layer_norm(x, scale, bias, cfg.norm_eps)
+
+
+def rope_tables(cfg: ArchConfig, positions: jnp.ndarray
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """cos/sin ``(..., S, rotary_dim // 2)`` for integer positions: the
+    frequencies run over the rotated dimensions only."""
+    return common.rope_cos_sin(positions, max(cfg.rotary_dim, 2),
+                               cfg.rope_theta)
 
 
 # ---------------------------------------------------------------------------
@@ -29,13 +75,13 @@ def block_stack_init(key, cfg: ArchConfig, n: int, *, cross: bool = False,
     ka, kc, kf, kn = jax.random.split(key, 4)
     hd = cfg.resolved_head_dim
     p: Dict = {
-        "ln_attn": common.rms_norm_init(n, cfg.d_model, dtype),
-        "ln_mlp": common.rms_norm_init(n, cfg.d_model, dtype),
+        "ln_attn": norm_init(cfg, n, dtype),
+        "ln_mlp": norm_init(cfg, n, dtype),
         "attn": attn.attn_init(ka, n, cfg.d_model, cfg.num_heads,
                                cfg.num_kv_heads, hd, cfg.qkv_bias, dtype),
     }
     if cross:
-        p["ln_cross"] = common.rms_norm_init(n, cfg.d_model, dtype)
+        p["ln_cross"] = norm_init(cfg, n, dtype)
         p["cross"] = attn.cross_attn_init(kc, n, cfg.d_model, cfg.num_heads,
                                           cfg.num_kv_heads, hd, dtype)
     if cfg.family == ArchFamily.MOE:
@@ -49,7 +95,7 @@ def lm_head_init(key, cfg: ArchConfig, dtype=jnp.float32) -> Dict:
     ke, ko = jax.random.split(key)
     p = {
         "embed": common.embed_init(ke, cfg.padded_vocab, cfg.d_model, dtype),
-        "ln_f": common.rms_norm_init(None, cfg.d_model, dtype),
+        "ln_f": norm_init(cfg, None, dtype),
     }
     if not cfg.tie_embeddings:
         p["unembed"] = common.dense_init(ko, cfg.d_model, cfg.padded_vocab, dtype)
@@ -103,7 +149,7 @@ def block_apply(p_l: Dict, x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
         return jax.lax.with_sharding_constraint(t, seq_shardings[0]) \
             if seq_shardings else t
 
-    h = gather(common.rms_norm(x, p_l["ln_attn"], cfg.norm_eps))
+    h = gather(norm(x, p_l["ln_attn"], cfg))
     q, k, v = attn.qkv_project(h, p_l["attn"], cfg.num_heads, cfg.num_kv_heads, hd)
     q = common.apply_rope(q, cos[:, :, None, :], sin[:, :, None, :])
     k = common.apply_rope(k, cos[:, :, None, :], sin[:, :, None, :])
@@ -111,11 +157,11 @@ def block_apply(p_l: Dict, x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray,
     x = x + gate * scatter(attn.output_project(o, p_l["attn"]))
 
     if enc_kv is not None:
-        h = gather(common.rms_norm(x, p_l["ln_cross"], cfg.norm_eps))
+        h = gather(norm(x, p_l["ln_cross"], cfg))
         x = x + gate * scatter(attn.cross_attend(
             h, enc_kv, p_l["cross"], cfg.num_heads, cfg.num_kv_heads, hd))
 
-    h = gather(common.rms_norm(x, p_l["ln_mlp"], cfg.norm_eps))
+    h = gather(norm(x, p_l["ln_mlp"], cfg))
     delta, aux = _ffn(p_l, h, cfg)
     x = x + gate * scatter(delta)
     return x, gate * aux
@@ -179,7 +225,7 @@ def decode_block_apply(p_l: Dict, x: jnp.ndarray, cos: jnp.ndarray,
     ``sp_decode = (mesh, batch_axes)`` switches cache attention to the
     explicit sequence-parallel flash-decode merge (§Perf)."""
     hd = cfg.resolved_head_dim
-    h = common.rms_norm(x, p_l["ln_attn"], cfg.norm_eps)
+    h = norm(x, p_l["ln_attn"], cfg)
     q, k, v = attn.qkv_project(h, p_l["attn"], cfg.num_heads, cfg.num_kv_heads, hd)
     q = common.apply_rope(q, cos[:, :, None, :], sin[:, :, None, :])
     k = common.apply_rope(k, cos[:, :, None, :], sin[:, :, None, :])
@@ -192,11 +238,11 @@ def decode_block_apply(p_l: Dict, x: jnp.ndarray, cos: jnp.ndarray,
     x = x + attn.output_project(o, p_l["attn"])
 
     if enc_kv is not None:
-        h = common.rms_norm(x, p_l["ln_cross"], cfg.norm_eps)
+        h = norm(x, p_l["ln_cross"], cfg)
         x = x + attn.cross_attend(h, enc_kv, p_l["cross"],
                                   cfg.num_heads, cfg.num_kv_heads, hd)
 
-    h = common.rms_norm(x, p_l["ln_mlp"], cfg.norm_eps)
+    h = norm(x, p_l["ln_mlp"], cfg)
     delta, _ = _ffn(p_l, h, cfg)
     return x + delta, cache_k, cache_v
 
@@ -234,11 +280,12 @@ def embed(params: Dict, tokens: jnp.ndarray, cfg: ArchConfig,
 
 
 def lm_logits(params: Dict, x: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
-    h = common.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        # a paired split flow runs its head on the partner's copy of the
-        # tied matrix, which the vmapped engine passes as ``tied_head``
-        w = params.get("tied_head", params["embed"]).T
-    else:
-        w = params["unembed"]
-    return jnp.einsum("bsd,dv->bsv", h, w.astype(h.dtype))
+    with jax.named_scope("lm_head"):
+        h = norm(x, params["ln_f"], cfg)
+        if cfg.tie_embeddings:
+            # a paired split flow runs its head on the partner's copy of the
+            # tied matrix, which the vmapped engine passes as ``tied_head``
+            w = params.get("tied_head", params["embed"]).T
+        else:
+            w = params["unembed"]
+        return jnp.einsum("bsd,dv->bsv", h, w.astype(h.dtype))

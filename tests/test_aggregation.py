@@ -1,6 +1,7 @@
-"""The round's aggregation as two jitted programs: the weights are
-composed on the host, the reduce and the broadcast each run as one
-program over the whole tree, and the results equal the per-leaf eager
+"""The round's aggregation as jitted programs: the weights are composed
+on the host, the reduce and the broadcast each run as one program over
+the whole tree, or together as one program written into the donated
+replicas (``aggregate_into``), and the results equal the per-leaf eager
 reduction they replace (kept below as the reference)."""
 import os
 import subprocess
@@ -221,6 +222,86 @@ def test_broadcast_into_spent_replicas_reuses_their_buffers():
         assert (o.sharding, o.committed) == (r.sharding, r.committed)
 
 
+def _copy(tree):
+    return jax.tree_util.tree_map(jnp.copy, tree)
+
+
+@pytest.mark.parametrize("stale", sorted(STALENESS))
+@pytest.mark.parametrize("mask", sorted(MASKS))
+@pytest.mark.parametrize("mode", ["paper", "fedavg"])
+def test_aggregate_into_is_the_broadcast_of_the_reduce(mode, mask, stale):
+    tree = _tree(8)
+    kw = dict(active=MASKS[mask], staleness=STALENESS[stale])
+    want = aggregation.broadcast(
+        aggregation.aggregate(_copy(tree), AGG_W, mode, **kw), N)
+    got = aggregation.aggregate_into(tree, AGG_W, mode, **kw)
+    for g, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+        assert (g.sharding, g.committed) == (r.sharding, r.committed)
+
+
+def test_aggregate_into_writes_into_the_donated_replicas(on):
+    tree = _tree(9)
+    buffers = [a.unsafe_buffer_pointer()
+               for a in jax.tree_util.tree_leaves(tree)]
+    with jax.transfer_guard_device_to_host("disallow"):
+        out = aggregation.aggregate_into(tree, AGG_W, "fedavg",
+                                         active=MASKS["partial"],
+                                         staleness=STALENESS["positive"])
+    # one program, no device read, nothing allocated for the model
+    assert (_counts("host_sync"), _counts("agg_program")) == (0, 1)
+    assert [a.unsafe_buffer_pointer()
+            for a in jax.tree_util.tree_leaves(out)] == buffers
+    assert all(a.is_deleted() for a in jax.tree_util.tree_leaves(tree))
+
+
+def test_aggregate_into_refuses_an_empty_cohort_before_donating(on):
+    tree = _tree(10)
+    with pytest.raises(aggregation.EmptyCohortError, match="round 3"):
+        aggregation.aggregate_into(tree, AGG_W, "fedavg",
+                                   active=np.zeros(N, bool), round_idx=3)
+    assert _counts("agg_program") == 0
+    assert not any(a.is_deleted() for a in jax.tree_util.tree_leaves(tree))
+
+
+@pytest.mark.parametrize("mode", ["paper", "fedavg"])
+def test_nan_in_an_excluded_replica_never_reaches_the_replicas(mode):
+    tree = _tree(11)
+    poisoned = jax.tree_util.tree_map(lambda a: a.at[1].set(jnp.nan), tree)
+    clean = aggregation.aggregate_into(tree, AGG_W, mode,
+                                       active=MASKS["partial"])
+    got = aggregation.aggregate_into(poisoned, AGG_W, mode,
+                                     active=MASKS["partial"])
+    for g, c in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(clean)):
+        assert np.isfinite(np.asarray(g)).all()
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(c))
+
+
+@pytest.mark.parametrize("spec", ["mean", "scaffold"])
+def test_policy_into_replicas_is_apply_then_broadcast(spec):
+    pol = aggregation.get_aggregation_policy(spec)
+    tree = _tree(12)
+    g0 = jax.tree_util.tree_map(lambda a: a[0] * 0.5, tree)
+    ctx = aggregation.AggContext(
+        prev_global=g0, partner=np.array([1, 0, 3, 2]),
+        lengths=np.array([1, 2, 1, 2]), num_layers=3, lr=0.1, steps=2)
+    kw = dict(active=MASKS["partial"], ctx=ctx, round_idx=0)
+    g, st_ref = pol.apply(_copy(tree), AGG_W, "fedavg",
+                          state=pol.init_state(g0, N), **kw)
+    want = aggregation.broadcast(g, N)
+    got, st = pol.apply(tree, AGG_W, "fedavg", state=pol.init_state(g0, N),
+                        into_replicas=True, **kw)
+    assert all(a.is_deleted() for a in jax.tree_util.tree_leaves(tree))
+    for o, r in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_array_equal(np.asarray(o), np.asarray(r))
+    for a, b in zip(jax.tree_util.tree_leaves(pol.state_tree(st)),
+                    jax.tree_util.tree_leaves(pol.state_tree(st_ref))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 FOUR_DEVICE_CODE = r"""
 import jax
 import jax.numpy as jnp
@@ -261,6 +342,22 @@ for n in (4, 8):
     for a, r in zip(jax.tree_util.tree_leaves(again),
                     jax.tree_util.tree_leaves(ref)):
         assert a.sharding == r.sharding
+    # the reduce and the broadcast in one program: the same shards and
+    # buffers, every row the masked mean
+    mean = aggregation.aggregate(again, np.ones(n), "fedavg",
+                                 active=np.arange(n) % 2 == 0)
+    into = aggregation.aggregate_into(again, np.ones(n), "fedavg",
+                                      active=np.arange(n) % 2 == 0,
+                                      sharding=sh)
+    assert [s.data.unsafe_buffer_pointer()
+            for a in jax.tree_util.tree_leaves(into)
+            for s in a.addressable_shards] == buffers
+    for a, r, m in zip(jax.tree_util.tree_leaves(into),
+                       jax.tree_util.tree_leaves(ref),
+                       jax.tree_util.tree_leaves(mean)):
+        assert a.sharding == r.sharding
+        np.testing.assert_array_equal(
+            np.asarray(a), np.broadcast_to(np.asarray(m)[None], a.shape))
 print("ok")
 """
 

@@ -3,9 +3,11 @@
 The TPU compiler refuses what interpret mode accepts — block shapes whose
 last two dims are not (8, 128)-aligned or whole, programs that do not fit
 the chip's memory — so the main path's kernels at real widths and the
-vmapped / bucketed fed steps at qwen1.5-0.5b's published widths are
-compiled here for a ``v5e:2x2`` topology.  Nothing runs: these tests say
-that the chip's compiler accepts the programs, not what they compute.
+vmapped / bucketed fed steps at qwen1.5-0.5b's published widths (and the
+vmapped one and the in-place aggregation at stablelm-2-1.6b's, 6 of its
+24 layers) are compiled here for a ``v5e:2x2`` topology.  Nothing runs:
+these tests say that the chip's compiler accepts the programs, and what
+they reserve, not what they compute.
 
 The topology is described inside a fixture (never at import), so every
 xdist worker collects the same tests and only the worker given this file
@@ -19,7 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
-from repro.core import fedbucket, fedpair, splitting
+from repro.core import aggregation, fedbucket, fedpair, splitting
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd
 from repro.kernels.wkv6 import wkv6
@@ -89,8 +91,8 @@ QWEN = get_config("qwen1.5-0.5b")
 N_CLIENTS, BATCH, SEQ = 2, 2, 512
 
 
-def _fed_inputs(sharding):
-    g = jax.eval_shape(lambda k: registry.init_params(QWEN, k),
+def _fed_inputs(sharding, cfg=QWEN):
+    g = jax.eval_shape(lambda k: registry.init_params(cfg, k),
                        jax.random.key(0))
     params = jax.tree_util.tree_map(
         lambda a: _spec(sharding, (N_CLIENTS,) + a.shape, a.dtype), g)
@@ -122,3 +124,40 @@ def test_bucketed_fed_step_compiles_at_qwen_published_widths(one_chip):
         QWEN, np.array([1, 0]), np.array([9, 15]),
         np.array([0.5, 0.5], np.float32), fedbucket.FedBucketConfig(lr=0.05))
     _assert_fits(step.lower(params, batch).compile())
+
+
+def test_vmapped_fed_step_compiles_at_stablelm2_widths(one_chip):
+    """The benchmark's StableLM 2 cell: published widths at 6 layers,
+    LayerNorm, partial rope, QKV bias and the untied 100k head on the
+    partner."""
+    cfg = get_config("stablelm-1.6b").with_overrides(num_layers=6)
+    g, params, batch = _fed_inputs(one_chip, cfg)
+    step = fedpair.make_fed_step(
+        lambda p, b: registry.loss_fn(p, b, cfg)[0],
+        splitting.split_plan(cfg, g), cfg.num_layers,
+        fedpair.FedPairingConfig(lr=0.05 / N_CLIENTS))
+    vec = lambda dtype: _spec(one_chip, (N_CLIENTS,), dtype)
+    compiled = step.lower(params, batch, vec(jnp.int32), vec(jnp.int32),
+                          vec(jnp.float32)).compile()
+    _assert_fits(compiled)
+
+
+def test_in_place_aggregation_allocates_no_model_at_stablelm2_widths(
+        one_chip):
+    """The round's reduce and broadcast as one program written into the
+    donated replicas: every output aliases an argument and the only
+    temporary is one leaf's mean, so nothing model-sized is allocated
+    while the last local step may still hold its reservation."""
+    cfg = get_config("stablelm-1.6b").with_overrides(num_layers=6)
+    g, params, _ = _fed_inputs(one_chip, cfg)
+    w = _spec(one_chip, (N_CLIENTS,))
+    m = aggregation._reduce_into_program(None).lower(
+        w, params).compile().memory_analysis()
+    # beyond the aliased buffers the outputs count a few hundred bytes
+    # of the runtime's own (512 here), never a leaf
+    assert m.alias_size_in_bytes > 0
+    assert m.output_size_in_bytes - m.alias_size_in_bytes < 2 ** 20
+    largest = max(a.size * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(g))
+    # one leaf's mean, and 64 KiB of scratch here
+    assert m.temp_size_in_bytes <= largest + 2 ** 20

@@ -187,6 +187,39 @@ def test_bucketed_matches_vmapped_mix_core_with_tied_embeddings(aggregation):
     _tree_allclose(new_v, new_b, rtol=5e-4, atol=5e-5)
 
 
+@pytest.mark.parametrize("aggregation", ["paper", "fedavg"])
+def test_bucketed_matches_vmapped_mix_core_stablelm2(aggregation):
+    """StableLM 2's LayerNorm (random biases here), rope over 16 of 64 head
+    dimensions, QKV bias and untied head on the partner: both engines build
+    their rope tables with the one helper."""
+    cfg = get_smoke_config("stablelm-1.6b").with_overrides(num_layers=W)
+    n = 4
+    partner, lengths = np.array([1, 0, 3, 2]), np.array([1, 3, 2, 2])
+    g = registry.init_params(cfg, jax.random.key(0))
+    g["blocks"]["ln_attn"] = g["blocks"]["ln_attn"] + 0.1 * jax.random.normal(
+        jax.random.key(2), g["blocks"]["ln_attn"].shape)
+    g["ln_f"] = g["ln_f"] + 0.1 * jax.random.normal(jax.random.key(3),
+                                                    g["ln_f"].shape)
+    cp = fedpair.replicate(g, n)
+    tokens = jax.random.randint(jax.random.key(1), (n, 2, 16), 0,
+                                cfg.vocab_size)
+    batch = {"tokens": tokens, "labels": tokens}
+    agg_w = fedpair.pair_weights(np.arange(1.0, n + 1), partner)
+    step_b, _ = fedbucket.make_bucketed_fed_step(
+        cfg, partner, lengths, agg_w,
+        fedbucket.FedBucketConfig(lr=0.1, aggregation=aggregation,
+                                  donate=False))
+    new_b, _ = step_b(cp, batch)
+    step_v = fedpair.make_fed_step(
+        lambda p, b: registry.loss_fn(p, b, cfg)[0],
+        splitting.split_plan(cfg, g), W,
+        fedpair.FedPairingConfig(lr=0.1 / n, aggregation=aggregation,
+                                 donate=False))
+    new_v, _ = step_v(cp, batch, jnp.asarray(partner), jnp.asarray(lengths),
+                      jnp.asarray(agg_w))
+    _tree_allclose(new_v, new_b, rtol=5e-4, atol=5e-5)
+
+
 def test_step_donates_client_params():
     cp, batch = _setup(2)
     step, _ = fedbucket.make_bucketed_fed_step(

@@ -2,6 +2,8 @@
 vanilla FL, vanilla SL, SplitFed) run on the same federated image task;
 FedPairing + dist-engine equivalence; full-pipeline integration."""
 import functools
+import os
+import pathlib
 import subprocess
 import sys
 
@@ -117,11 +119,7 @@ def test_all_baselines_learn(task):
     assert _accuracy(merged, test) > 0.25
 
 
-def test_dist_engine_matches_vmapped_semantics():
-    """shard_map+ppermute engine == vmapped mix-params engine (up to the
-    1/N loss normalization).  Runs in a subprocess with 4 fabricated
-    devices so this process's device count stays 1."""
-    code = r"""
+_DIST_EQUIV = r"""
 import os
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
@@ -131,7 +129,7 @@ from repro.configs import get_smoke_config
 from repro.core import fedpair, fedpair_dist, splitting
 from repro.models import registry
 
-cfg = get_smoke_config("tinyllama-1.1b")
+cfg = get_smoke_config("ARCH_ID")
 n = 4
 partner = np.array([1, 0, 3, 2])
 lengths = np.array([1, 1, 1, 1])
@@ -170,8 +168,27 @@ for (pa, a), (pb, b) in zip(
                                atol=5e-5, err_msg=str(pa))
 print("DIST_EQUIV_OK")
 """
+
+
+def _dist_equiv(arch_id):
+    code = _DIST_EQUIV.replace("ARCH_ID", arch_id)
     res = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                                          "HOME": "/root"}, cwd="/root/repo",
+                         text=True, env={"PYTHONPATH": "src",
+                                         "PATH": os.environ.get("PATH", ""),
+                                         "HOME": os.environ.get("HOME", "")},
+                         cwd=pathlib.Path(__file__).resolve().parents[1],
                          timeout=600)
     assert "DIST_EQUIV_OK" in res.stdout, res.stdout + res.stderr
+
+
+def test_dist_engine_matches_vmapped_semantics():
+    """shard_map+ppermute engine == vmapped mix-params engine (up to the
+    1/N loss normalization).  Runs in a subprocess with 4 fabricated
+    devices so this process's device count stays 1."""
+    _dist_equiv("tinyllama-1.1b")
+
+
+def test_dist_engine_matches_vmapped_semantics_stablelm2():
+    """The same with StableLM 2's LayerNorm, partial rope, QKV bias and
+    untied head: both engines build their rope tables with the one helper."""
+    _dist_equiv("stablelm-1.6b")

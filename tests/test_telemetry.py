@@ -110,7 +110,7 @@ def test_fedpairing_round_spans_and_host_syncs(on):
             s.name for s in spans if _parent(s, spans) is r)
         assert children == {"plan": 1, "batch": 2, "step": 2,
                             "loss_sync": 2, "clock": 1, "aggregate": 1,
-                            "broadcast": 1, "record": 1}
+                            "record": 1}
         for step in (s for s in spans
                      if s.name == "step" and _parent(s, spans) is r):
             assert sorted(s.name for s in spans
@@ -122,15 +122,15 @@ def test_fedpairing_round_spans_and_host_syncs(on):
         syncs = [_parent(c, spans).name for c in in_round
                  if c.name == "host_sync"]
         assert sorted(syncs) == ["loss_sync", "loss_sync"]
-        # one jitted reduce in `aggregate`, one jitted broadcast
+        # one jitted program in `aggregate`: the reduce and the broadcast,
+        # written into the trained replicas
         programs = [_parent(c, spans).name for c in in_round
                     if c.name == "agg_program"]
-        assert sorted(programs) == ["aggregate", "broadcast"]
+        assert programs == ["aggregate"]
 
 
 @pytest.mark.parametrize("algorithm,names", [
-    ("fl", {"round", "batch", "step", "loss_sync", "aggregate",
-            "broadcast"}),
+    ("fl", {"round", "batch", "step", "loss_sync", "aggregate"}),
     ("splitfed", {"round", "batch", "step", "loss_sync", "aggregate",
                   "broadcast"}),
     ("sl", {"round", "batch", "step", "loss_sync"}),
@@ -140,12 +140,19 @@ def test_baseline_rounds_have_their_spans(on, algorithm, names):
     assert {s.name for s in _spans()} == names
 
 
+def test_scaffold_broadcasts_inside_its_aggregate_span(on):
+    _driver(agg_policy="scaffold").run(rounds=1)
+    spans = _spans()
+    (bcast,) = [s for s in spans if s.name == "broadcast"]
+    assert _parent(bcast, spans).name == "aggregate"
+
+
 def test_faulted_round_has_every_span(on):
     fc = faults.FaultConfig(straggler=0.5)
     _driver(n=4, faults=fc).run(rounds=1)
     assert {s.name for s in _spans()} >= {
         "round", "plan", "batch", "step", "step.args", "step.dispatch",
-        "loss_sync", "clock", "aggregate", "broadcast", "record"}
+        "loss_sync", "clock", "aggregate", "record"}
 
 
 def test_parameters_are_bit_identical_with_telemetry_on_and_off():
