@@ -18,13 +18,18 @@ Self-paired clients (odd N) degenerate to plain local SGD automatically:
 partner == self makes the mix the identity and both gradient terms the
 client's own.
 
-Perf notes (DESIGN.md §Perf): the step fuses the partner gather into the
-mix (the partner parameter tree is never materialized as a second full
-copy), fuses the gradient routing + involution return + Eq. (7) overlap
-factor into the single SGD parameter write, and donates the client-param
-buffers so the fleet updates in place.  A step therefore consumes the
-parameter tree you pass it — thread the returned tree forward, or build
-the step with ``FedPairingConfig(donate=False)`` to keep inputs alive.
+Perf notes (DESIGN.md §Perf): up to ``SLICE_MAX_CLIENTS`` clients on one
+device the step reads each partner's rows as one static slice per client
+at a traced offset, which the TPU compiler fuses into the mix, the
+routed gradients and the SGD write (a traced-index gather ``x[partner]``
+became a loop writing a whole copy of the leaf).
+The tied head is selected after its cast to the compute dtype, so the
+partner's matrix is never held as a second float32 copy.  The gradient
+routing + involution return + Eq. (7) overlap factor form the single SGD
+parameter write, and the client-param buffers are donated so the fleet
+updates in place.  A step therefore consumes the parameter tree you pass
+it — thread the returned tree forward, or build the step with
+``FedPairingConfig(donate=False)`` to keep inputs alive.
 For length-bucketed execution that also skips the gated-off blocks' FLOPs
 entirely, see ``core.fedbucket``.
 """
@@ -79,16 +84,42 @@ def replicate(params: Dict, n: int, sharding=None) -> Dict:
     return out if sharding is None else sharding.place(out)
 
 
+# Above this many clients the step keeps the gather ``x[partner]``: the
+# slices grow the program with N x leaves; up to 8 their CPU step is within
+# 10 % of the gather's, from 12 on it takes 1.1-1.7 times as long (PERF.md §6).
+SLICE_MAX_CLIENTS = 8
+
+
+def _partner_rows(x: jnp.ndarray, partner: jnp.ndarray) -> jnp.ndarray:
+    """``x[partner]``.  Up to ``SLICE_MAX_CLIENTS`` clients (a static
+    shape) on one device, as N one-row slices at traced offsets: the TPU
+    compiler fuses these into their consumer, where it lowers the
+    traced-index gather to a loop that writes a whole copy of ``x``.  On
+    a mesh of several devices (a fleet-sharded client axis) a slice at a
+    traced offset is a collective of its own, and the gather is kept.
+    Either form copies the rows as they are, so the values are the same."""
+    n = partner.shape[0]
+    if n > SLICE_MAX_CLIENTS or jax.typeof(x).sharding.mesh.size > 1:
+        return x[partner]
+    return jnp.concatenate([jax.lax.dynamic_index_in_dim(x, partner[i], 0)
+                            for i in range(n)])
+
+
 def make_fed_step(loss_fn: LossFn, plan: Dict, num_layers: int,
-                  fed_cfg: FedPairingConfig, tied_head: bool = False):
+                  fed_cfg: FedPairingConfig, tied_head_dtype=None):
     """Build the jitted per-batch FedPairing step.
 
-    ``tied_head``: the model ties its output head to ``params["embed"]``
-    (``ArchConfig.tie_embeddings``).  Flow i embeds its tokens with its own
-    matrix, but its head runs on the partner — the top owner — so the
-    loss sees the partner's matrix as ``tied_head`` and that gradient
-    returns to the partner's ``embed``, as in the bucketed and dist
-    engines.
+    ``tied_head_dtype``: None, or the model ties its output head to
+    ``params["embed"]`` (``ArchConfig.tie_embeddings``) and its loss casts
+    that matrix to this dtype (``ArchConfig.dtype``).  Flow i embeds its
+    tokens with its own matrix, but its head runs on the partner — the
+    top owner — so the loss sees the partner's matrix as ``tied_head``
+    and that gradient returns to the partner's ``embed``, as in the
+    bucketed and dist engines.  The partner's rows are selected after
+    the cast, so no float32 copy of its matrix is held.  The loss must
+    make that cast exactly once, outside any scan, so the head's gradient
+    is rounded to this dtype once, as it is on the float32 matrix; under
+    a scan it would accumulate in this dtype.
 
     Returns ``step(client_params, batches, partner, lengths, agg_w)`` where
     * client_params — pytree stacked over N clients (donated unless
@@ -107,30 +138,31 @@ def make_fed_step(loss_fn: LossFn, plan: Dict, num_layers: int,
                        donate_argnums=(0,) if fed_cfg.donate else ())
     def step(client_params, batches, partner, lengths, agg_w):
         n = partner.shape[0]
+        rows = functools.partial(_partner_rows, partner=partner)
         masks = jax.vmap(splitting.layer_mask, in_axes=(0, None))(
             lengths, num_layers)                                 # (N, W)
-        masks_p = masks[partner]
+        masks_p = rows(masks)
 
-        # fused gather+mix: bottom/stack[<L] from own, rest from the
-        # partner — gathered leaf-wise, never held as a full partner tree.
+        # bottom/stack[<L] from own, rest from the partner's rows
         def mix(a, label):
             if label == "bottom":
                 return a
             if label == "top":
-                return a[partner]
+                return rows(a)
             m = _bmask(masks, a)
-            return a * m + a[partner] * (1.0 - m)
+            return a * m + rows(a) * (1.0 - m)
 
         mixed = jax.tree_util.tree_map(mix, client_params, plan)
-        if tied_head:
-            mixed = dict(mixed, tied_head=client_params["embed"][partner])
+        if tied_head_dtype is not None:
+            head = client_params["embed"].astype(tied_head_dtype)
+            mixed = dict(mixed, tied_head=rows(head))
         losses, g_mix = jax.vmap(jax.value_and_grad(loss_fn))(mixed, batches)
-        if tied_head:
+        if tied_head_dtype is not None:
             g_mix = dict(g_mix)
             g_head = g_mix.pop("tied_head")
 
         if fed_cfg.aggregation == "paper":
-            a_own, a_in = agg_w, agg_w[partner]
+            a_own, a_in = agg_w, rows(agg_w)
         else:  # weighting deferred to the server aggregation
             a_own = a_in = jnp.ones_like(agg_w)
         factor = jax.vmap(splitting.overlap_factor, in_axes=(0, 0, None))(
@@ -144,20 +176,20 @@ def make_fed_step(loss_fn: LossFn, plan: Dict, num_layers: int,
             if label == "bottom":
                 u = a_own.reshape(b) * g
             elif label == "top":
-                u = a_in.reshape(b) * g[partner]
+                u = a_in.reshape(b) * rows(g)
             else:
                 m = _bmask(masks, g)
                 u = (a_own.reshape(b) * (g * m)
-                     + a_in.reshape(b) * (g[partner] * (1.0 - _bmask(masks_p, g))))
+                     + a_in.reshape(b) * (rows(g) * (1.0 - _bmask(masks_p, g))))
                 u = u * _bmask(factor, g).astype(u.dtype)
             return p - fed_cfg.lr * u
 
         new_params = jax.tree_util.tree_map(apply, client_params, g_mix, plan)
-        if tied_head:
+        if tied_head_dtype is not None:
             # the partner flow's head gradient on my matrix: a "top" term
             b = (n,) + (1,) * (g_head.ndim - 1)
             new_params["embed"] = new_params["embed"] - fed_cfg.lr * (
-                a_in.reshape(b) * g_head[partner])
+                a_in.reshape(b) * rows(g_head))
         return new_params, {"loss": losses}
 
     return step

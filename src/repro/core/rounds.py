@@ -286,10 +286,10 @@ class _VmappedEngine:
         fed_cfg = fedpair.FedPairingConfig(
             lr=rc.lr / n, overlap_boost=rc.overlap_boost,
             aggregation=rc.aggregation, donate=rc.donate)
+        tied = getattr(cfg, "tie_embeddings", False) and "embed" in gparams
         self._step = fedpair.make_fed_step(
             loss_fn, plan, cfg.num_layers, fed_cfg,
-            tied_head=getattr(cfg, "tie_embeddings", False)
-            and "embed" in gparams)
+            tied_head_dtype=cfg.dtype if tied else None)
         self.cached_steps = 1
 
     def step(self, params, batch, plan: RoundPlan, agg_w):

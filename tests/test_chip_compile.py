@@ -7,12 +7,15 @@ vmapped / bucketed fed steps at qwen1.5-0.5b's published widths (and the
 vmapped one and the in-place aggregation at stablelm-2-1.6b's, 6 of its
 24 layers) are compiled here for a ``v5e:2x2`` topology.  Nothing runs:
 these tests say that the chip's compiler accepts the programs, and what
-they reserve, not what they compute.
+they reserve, not what they compute; for the vmapped steps also that no
+partner read was lowered to a gather loop.
 
 The topology is described inside a fixture (never at import), so every
 xdist worker collects the same tests and only the worker given this file
 loads the TPU compiler.
 """
+import re
+
 import numpy as np
 import pytest
 
@@ -106,16 +109,36 @@ def _assert_fits(compiled):
     assert 0 < peak <= V5E_HBM_BYTES, peak
 
 
+def _gather_loops(compiled) -> int:
+    """``while`` ops the TPU compiler made of a gather.  A traced-index
+    partner read ``x[partner]`` becomes one such loop per leaf and use,
+    each writing a whole copy of the leaf."""
+    return sum(1 for line in compiled.as_text().splitlines()
+               if re.search(r"\bwhile\(", line)
+               and re.search(r'op_name="[^"]*/gather"', line))
+
+
+def _assert_lean_fed_step(compiled, gather_step_peak):
+    """No partner read left as a gather loop, and the compiled peak within
+    1 % of the gather form's (its value here when the step read partners
+    with ``x[partner]``)."""
+    assert _gather_loops(compiled) == 0
+    peak = compiled.memory_analysis().peak_memory_in_bytes
+    assert peak <= 1.01 * gather_step_peak, peak
+
+
 def test_vmapped_fed_step_compiles_at_qwen_published_widths(one_chip):
     g, params, batch = _fed_inputs(one_chip)
     step = fedpair.make_fed_step(
         lambda p, b: registry.loss_fn(p, b, QWEN)[0],
         splitting.split_plan(QWEN, g), QWEN.num_layers,
-        fedpair.FedPairingConfig(lr=0.05 / N_CLIENTS), tied_head=True)
+        fedpair.FedPairingConfig(lr=0.05 / N_CLIENTS),
+        tied_head_dtype=QWEN.dtype)
     vec = lambda dtype: _spec(one_chip, (N_CLIENTS,), dtype)
     compiled = step.lower(params, batch, vec(jnp.int32), vec(jnp.int32),
                           vec(jnp.float32)).compile()
     _assert_fits(compiled)
+    _assert_lean_fed_step(compiled, 13_941_535_744)
 
 
 def test_bucketed_fed_step_compiles_at_qwen_published_widths(one_chip):
@@ -140,6 +163,7 @@ def test_vmapped_fed_step_compiles_at_stablelm2_widths(one_chip):
     compiled = step.lower(params, batch, vec(jnp.int32), vec(jnp.int32),
                           vec(jnp.float32)).compile()
     _assert_fits(compiled)
+    _assert_lean_fed_step(compiled, 14_473_285_120)
 
 
 def test_in_place_aggregation_allocates_no_model_at_stablelm2_widths(

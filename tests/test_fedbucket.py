@@ -161,7 +161,7 @@ def test_bucketed_matches_vmapped_mix_core(aggregation):
 @pytest.mark.parametrize("aggregation", ["paper", "fedavg"])
 def test_bucketed_matches_vmapped_mix_core_with_tied_embeddings(aggregation):
     """With a tied head the flow's head runs on the partner's embedding
-    matrix in both engines (``fedpair.make_fed_step(tied_head=True)``)."""
+    matrix in both engines (``fedpair.make_fed_step(tied_head_dtype=)``)."""
     cfg = get_smoke_config("qwen1.5-0.5b").with_overrides(num_layers=W)
     assert cfg.tie_embeddings
     n = 4
@@ -181,7 +181,7 @@ def test_bucketed_matches_vmapped_mix_core_with_tied_embeddings(aggregation):
         lambda p, b: registry.loss_fn(p, b, cfg)[0],
         splitting.split_plan(cfg, g), W,
         fedpair.FedPairingConfig(lr=0.1 / n, aggregation=aggregation,
-                                 donate=False), tied_head=True)
+                                 donate=False), tied_head_dtype=cfg.dtype)
     new_v, _ = step_v(cp, batch, jnp.asarray(partner), jnp.asarray(lengths),
                       jnp.asarray(agg_w))
     _tree_allclose(new_v, new_b, rtol=5e-4, atol=5e-5)

@@ -1,14 +1,19 @@
 """FedPairing step semantics — vs a hand-written per-client reference,
 degenerate cases, overlap boost, and round-level convergence."""
 import functools
+import json
+import os
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.configs import get_smoke_config
 from repro.core import aggregation, fedpair, splitting
-from repro.models import vision
+from repro.models import registry, vision
 
 CFG = vision.VisionConfig(num_layers=4, width=16, image_size=4, num_classes=3)
 LOSS = functools.partial(vision.vision_loss, cfg=CFG)
@@ -89,6 +94,167 @@ def test_step_matches_reference(lengths, boost):
                                    rtol=2e-5, atol=2e-6)
 
 
+def _gather_step(loss_fn, plan, num_layers, lr):
+    """The vmapped step of a tied-head model with every partner read a
+    traced-index gather ``x[partner]`` and the tied head selected in the
+    parameter dtype: the form the static row slices replaced, kept as
+    their reference."""
+
+    def bmask(masks, a):
+        return masks.astype(a.dtype).reshape(masks.shape + (1,) * (a.ndim - 2))
+
+    @jax.jit
+    def step(cp, batches, partner, lengths, agg_w):
+        n = partner.shape[0]
+        masks = jax.vmap(splitting.layer_mask, in_axes=(0, None))(
+            lengths, num_layers)
+        masks_p = masks[partner]
+
+        def mix(a, label):
+            if label == "bottom":
+                return a
+            if label == "top":
+                return a[partner]
+            m = bmask(masks, a)
+            return a * m + a[partner] * (1.0 - m)
+
+        mixed = dict(jax.tree_util.tree_map(mix, cp, plan),
+                     tied_head=cp["embed"][partner])
+        losses, g = jax.vmap(jax.value_and_grad(loss_fn))(mixed, batches)
+        g = dict(g)
+        g_head = g.pop("tied_head")
+        a_in = agg_w[partner]
+        factor = jax.vmap(splitting.overlap_factor, in_axes=(0, 0, None))(
+            masks, masks_p, True)
+
+        def apply(p, gl, label):
+            b = (n,) + (1,) * (gl.ndim - 1)
+            if label == "bottom":
+                u = agg_w.reshape(b) * gl
+            elif label == "top":
+                u = a_in.reshape(b) * gl[partner]
+            else:
+                u = (agg_w.reshape(b) * (gl * bmask(masks, gl))
+                     + a_in.reshape(b) * (gl[partner]
+                                          * (1.0 - bmask(masks_p, gl))))
+                u = u * bmask(factor, gl).astype(u.dtype)
+            return p - lr * u
+
+        new = jax.tree_util.tree_map(apply, cp, g, plan)
+        b = (n,) + (1,) * (g_head.ndim - 1)
+        new["embed"] = new["embed"] - lr * (a_in.reshape(b) * g_head[partner])
+        return new, losses
+
+    return step
+
+
+def _unequal_to_gather_step(partner, lengths, dtype):
+    """Run the step and its gather reference on one tied-head LM; return
+    the names of the outputs that differ in any bit or in dtype."""
+    w = 4
+    cfg = get_smoke_config("qwen1.5-0.5b").with_overrides(
+        num_layers=w, dtype=dtype)
+    assert cfg.tie_embeddings
+    n = len(partner)
+    g = registry.init_params(cfg, jax.random.key(0))
+    cp = fedpair.replicate(g, n)
+    tokens = jax.random.randint(jax.random.key(1), (n, 2, 16), 0,
+                                cfg.vocab_size)
+    batch = {"tokens": tokens, "labels": tokens}
+    agg_w = jnp.asarray(fedpair.pair_weights(np.arange(1.0, n + 1),
+                                             np.asarray(partner)))
+    args = (batch, jnp.asarray(partner, jnp.int32),
+            jnp.asarray(lengths, jnp.int32), agg_w)
+    loss = lambda p, b: registry.loss_fn(p, b, cfg)[0]
+    plan = splitting.split_plan(cfg, g)
+    want, want_loss = _gather_step(loss, plan, w, 0.1)(cp, *args)
+    got, got_m = fedpair.make_fed_step(
+        loss, plan, w, fedpair.FedPairingConfig(lr=0.1, donate=False),
+        tied_head_dtype=cfg.dtype)(cp, *args)
+    pairs = [("loss", got_m["loss"], want_loss)] + [
+        (jax.tree_util.keystr(path), a, b) for (path, a), (_, b) in zip(
+            jax.tree_util.tree_flatten_with_path(got)[0],
+            jax.tree_util.tree_flatten_with_path(want)[0])]
+    return [name for name, a, b in pairs
+            if a.dtype != b.dtype
+            or not np.array_equal(np.asarray(a), np.asarray(b))]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("partner, lengths", [
+    ([1, 0], [3, 2]), ([1, 0], [1, 3]),                    # one pair
+    ([1, 0, 2], [3, 2, 4]), ([1, 0, 2], [1, 3, 4]),        # + a self-pair
+    ([1, 0, 3, 2], [3, 2, 2, 3]), ([1, 0, 3, 2], [1, 3, 3, 1]),
+], ids=["n2-overlap", "n2-uneven", "n3-overlap", "n3-uneven",
+        "n4-overlap", "n4-uneven"])
+def test_static_row_slices_equal_the_gather_step(partner, lengths, dtype):
+    """The partner's rows read as static slices, and the tied head
+    selected after its cast to a bfloat16 compute dtype, give the same
+    bits as the gather form: the rows are copied, not recomputed.
+
+    XLA's CPU backend contracts a multiply and an add into one FMA as its
+    fusions happen to fall, and the two forms fuse differently, so the
+    comparison runs in a child interpreter whose instruction set stops at
+    AVX (no FMA): there both compile to the rounding their operations
+    state."""
+    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                       "src")
+    flags = os.environ.get("XLA_FLAGS", "") + " --xla_cpu_max_isa=AVX"
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+               XLA_FLAGS=flags.strip())
+    res = subprocess.run(
+        [sys.executable, os.path.abspath(__file__),
+         json.dumps([partner, lengths, dtype])],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert res.returncode == 0, res.stderr[-3000:]
+    assert json.loads(res.stdout.splitlines()[-1]) == []
+
+
+def _partner_rows_form(n, devices):
+    """Lower ``fedpair._partner_rows`` for N clients whose axis is split
+    over ``devices``; return the form it took (a gather, or the one-row
+    slices) and whether its rows equal ``x[partner]``."""
+    from jax.sharding import NamedSharding, PartitionSpec
+    assert jax.device_count() >= devices
+    x = jnp.arange(n * 6, dtype=jnp.float32).reshape(n, 2, 3)
+    if devices > 1:
+        mesh = jax.make_mesh((devices,), ("data",), axis_types=(
+            jax.sharding.AxisType.Auto,))
+        x = jax.device_put(x, NamedSharding(mesh, PartitionSpec("data")))
+    partner = jnp.asarray(np.arange(n)[::-1].copy(), jnp.int32)
+    rows = jax.jit(fedpair._partner_rows)
+    text = rows.lower(x, partner).as_text()
+    form = "gather" if "gather" in text else "slices"
+    equal = np.array_equal(np.asarray(rows(x, partner)),
+                           np.asarray(x)[np.asarray(partner)])
+    return [form, bool(equal)]
+
+
+@pytest.mark.parametrize("n, devices, form", [
+    (fedpair.SLICE_MAX_CLIENTS, 1, "slices"),
+    (fedpair.SLICE_MAX_CLIENTS + 1, 1, "gather"),
+    (4, 2, "gather"),
+], ids=["at-the-bound", "above-the-bound", "client-axis-on-a-mesh"])
+def test_partner_rows_form_follows_client_count_and_mesh(n, devices, form):
+    """Up to ``SLICE_MAX_CLIENTS`` clients on one device the partner's rows
+    are one-row slices; above it, or with the client axis split over a
+    mesh, the gather is kept.  Both give ``x[partner]``.  The mesh case
+    runs in a child interpreter with fabricated CPU devices."""
+    if devices == 1:
+        assert _partner_rows_form(n, devices) == [form, True]
+        return
+    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
+                       "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+               XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}")
+    res = subprocess.run(
+        [sys.executable, os.path.abspath(__file__),
+         json.dumps(["rows", n, devices])],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert res.returncode == 0, res.stderr[-3000:]
+    assert json.loads(res.stdout.splitlines()[-1]) == [form, True]
+
+
 def test_self_paired_client_is_local_sgd():
     """partner == self must reduce to plain (a_i-weighted) SGD."""
     g, cp = _clients(1)
@@ -167,3 +333,13 @@ def test_round_training_reduces_loss_and_aggregates():
     # after broadcast every client replica is identical
     for leaf in jax.tree_util.tree_leaves(cp):
         np.testing.assert_allclose(np.asarray(leaf[0]), np.asarray(leaf[-1]))
+
+
+if __name__ == "__main__":
+    # the child of test_static_row_slices_equal_the_gather_step, or of
+    # test_partner_rows_form_follows_client_count_and_mesh on a mesh
+    args = json.loads(sys.argv[1])
+    if args[0] == "rows":
+        print(json.dumps(_partner_rows_form(*args[1:])))
+    else:
+        print(json.dumps(_unequal_to_gather_step(*args)))
