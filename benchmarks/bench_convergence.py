@@ -260,19 +260,17 @@ class _RecordingMean(aggregation.MeanAggregation):
 
     def apply(self, client_params, agg_w, mode="paper", *, active=None,
               staleness=None, state=None, ctx=None, round_idx=None,
-              into_replicas=False, sharding=None):
-        # read before ``into_replicas`` donates the replicas
+              sharding=None):
+        # read before ``apply`` donates the replicas
         ref = aggregation.aggregate(client_params, agg_w, mode,
                                     active=active, staleness=staleness,
                                     round_idx=round_idx)
         out, st = super().apply(client_params, agg_w, mode, active=active,
                                 staleness=staleness, state=state, ctx=ctx,
-                                round_idx=round_idx,
-                                into_replicas=into_replicas,
-                                sharding=sharding)
-        if into_replicas:       # every row of the replicas is the mean
-            ref = jax.tree_util.tree_map(
-                lambda r, o: jnp.broadcast_to(r[None], o.shape), ref, out)
+                                round_idx=round_idx, sharding=sharding)
+        # every row of the replicas is the mean
+        ref = jax.tree_util.tree_map(
+            lambda r, o: jnp.broadcast_to(r[None], o.shape), ref, out)
         self.calls += 1
         if not all(bool(jnp.array_equal(a, b, equal_nan=True))
                    for a, b in zip(jax.tree_util.tree_leaves(out),

@@ -14,7 +14,8 @@ HOW the cohort's replicas become the next global model:
 
 * ``mean``     — the historical ``aggregate()`` below, stateless,
                  bit-identical to the pre-registry driver by construction
-                 (the policy literally delegates to it).
+                 (the policy runs its reduction, written into the
+                 replicas, through ``aggregate_into``).
 * ``scaffold`` — SCAFFOLD-style variance reduction (Karimireddy et al.,
                  arXiv 1910.06378): a server control variate ``c_global``
                  plus per-client variates ``c_local``, delta-based
@@ -301,13 +302,12 @@ class AggregationPolicy:
 
     ``stateful`` declares whether the policy carries cross-round state on
     ``RoundState`` (checkpointed, sharded); stateful policies also need
-    the per-round ``AggContext``.  ``apply`` returns ``(global_params,
-    new_state)`` — stateless policies return their input state untouched.
-    With ``into_replicas`` it returns the new model's stacked replicas in
-    place of ``global_params``, one per row of ``client_params`` and
-    placed by ``sharding``, written into the buffers of
-    ``client_params``, which are donated: the aggregation and the
-    broadcast in one call.
+    the per-round ``AggContext``.  ``apply`` returns ``(replicas,
+    new_state)``: the next global model's stacked replicas, one per row
+    of ``client_params`` and placed by ``sharding``, written into the
+    buffers of ``client_params``, which are donated — the aggregation
+    and the broadcast in one call.  Stateless policies return their
+    input state untouched.
     """
 
     spec: str = "?"
@@ -322,7 +322,7 @@ class AggregationPolicy:
               active: Optional[jnp.ndarray] = None,
               staleness: Optional[jnp.ndarray] = None,
               state=None, ctx: Optional[AggContext] = None,
-              round_idx: Optional[int] = None, into_replicas: bool = False,
+              round_idx: Optional[int] = None,
               sharding=None) -> Tuple[Dict, object]:
         raise NotImplementedError
 
@@ -343,22 +343,19 @@ class AggregationPolicy:
 
 
 class MeanAggregation(AggregationPolicy):
-    """The historical cohort-masked weighted mean — delegates to
-    ``aggregate()`` verbatim, so the registry's default is bit-identical
-    to the pre-registry driver by construction; ``into_replicas`` runs
-    the same reduction through ``aggregate_into``."""
+    """The historical cohort-masked weighted mean — ``aggregate()``'s
+    reduction and ``broadcast``'s replicas as one program
+    (``aggregate_into``), so every row it returns is bit-identical to
+    the pre-registry driver's global model."""
 
     spec = "mean"
 
     def apply(self, client_params, agg_w, mode="paper", *, active=None,
               staleness=None, state=None, ctx=None, round_idx=None,
-              into_replicas=False, sharding=None):
-        if into_replicas:
-            return aggregate_into(client_params, agg_w, mode, active=active,
-                                  staleness=staleness, round_idx=round_idx,
-                                  sharding=sharding), state
-        return aggregate(client_params, agg_w, mode, active=active,
-                         staleness=staleness, round_idx=round_idx), state
+              sharding=None):
+        return aggregate_into(client_params, agg_w, mode, active=active,
+                              staleness=staleness, round_idx=round_idx,
+                              sharding=sharding), state
 
 
 @dataclasses.dataclass
@@ -429,7 +426,7 @@ class ScaffoldAggregation(AggregationPolicy):
 
     def apply(self, client_params, agg_w, mode="paper", *, active=None,
               staleness=None, state=None, ctx=None, round_idx=None,
-              into_replicas=False, sharding=None):
+              sharding=None):
         if state is None or ctx is None:
             raise ValueError(
                 "scaffold aggregation needs its ScaffoldState and the "
@@ -495,9 +492,8 @@ class ScaffoldAggregation(AggregationPolicy):
             lambda cg, d: cg + jnp.sum(d, axis=0) / n,
             state.c_global,
             jax.tree_util.tree_unflatten(treedef, out_d))
-        if into_replicas:
-            with telemetry.span("broadcast"):
-                g = broadcast(g, n, sharding=sharding, into=client_params)
+        with telemetry.span("broadcast"):
+            g = broadcast(g, n, sharding=sharding, into=client_params)
         return g, ScaffoldState(c_global=c_global, c_local=c_local,
                                 applied=True)
 

@@ -127,9 +127,10 @@ class FaultConfig:
 
     @property
     def enabled(self) -> bool:
-        """True iff the fault layer can change anything.  When False the
-        driver takes the historical fault-free code path untouched — the
-        zero-cost guarantee the acceptance tests assert."""
+        """True iff the fault layer can change anything.  When False every
+        round realizes no fault, and the driver's one round path gives
+        the fault-free result bit for bit — the zero-cost guarantee the
+        acceptance tests assert."""
         drop = np.atleast_1d(np.asarray(self.dropout, np.float64))
         return bool(np.any(drop > 0) or self.straggler > 0
                     or self.outage > 0 or self.deadline_factor > 0)
@@ -218,10 +219,6 @@ class FaultModel:
         self.n = int(n)
         self.seed = (int(seed), int(cfg.seed))
         self._drop = cfg.dropout_probs(self.n)
-
-    @property
-    def enabled(self) -> bool:
-        return self.cfg.enabled
 
     def fail_prob(self) -> Optional[np.ndarray]:
         """(N,) per-client probability of NOT finishing a round — what the

@@ -25,12 +25,16 @@ DESIGN.md §5):
    the current rates, O(N^2)) instead of re-run (O(N^2 W)), invalidated
    by the same relative-drift signal (``RoundRecord.cut_cache`` records
    hit/miss/invalidated per round; DESIGN.md §8),
-4. executes ``batches_per_round`` fed steps on one of the three FedPairing
+4. (fedpairing) realizes the round's faults and evaluates the Eq. (3)
+   analytical latency of the executed plan into simulated wall-clock
+   (straggler = round max; ``faults.faulted_clock``, equal to
+   ``latency.round_clock_plan`` when no fault is realized); a round the
+   clock declares lost trains nothing.  The baselines accumulate their
+   own Eq. (3) clocks after training,
+5. executes ``batches_per_round`` fed steps on one of the three FedPairing
    engines — vmapped / bucketed / dist — or one of the paper's baselines
    (vanilla FL / vanilla SL / SplitFed from ``core.baselines``),
-5. applies pair-then-global aggregation over the cohort and broadcasts,
-6. accumulates the Eq. (3) analytical latency into simulated wall-clock
-   (straggler = round max; ``latency.round_time_from_partner``).
+6. applies pair-then-global aggregation over the cohort and broadcasts.
 
 All randomness flows from ONE ``np.random.Generator`` seeded with
 ``RoundConfig.seed`` and consumed in the order above, so two drivers with
@@ -113,9 +117,10 @@ class RoundConfig:
     server_cut: int = 0                 # sl/splitfed split; 0 -> W//2
     donate: bool = True                 # thread params in place (engines)
     seed: int = 0
-    # fault injection (core.faults): None -> the historical fault-free
-    # path, untouched.  A FaultConfig with all rates zero behaves
-    # identically (the zero-cost contract, DESIGN.md §9).
+    # fault injection (core.faults): None means FaultConfig().  Every
+    # fedpairing round runs through the fault layer; one that realizes
+    # no fault gives the fault-free result bit for bit (the zero-cost
+    # contract, DESIGN.md §9).
     faults: Optional[faults.FaultConfig] = None
     # async pipelined rounds (DESIGN.md §12): event-driven clock instead
     # of the round-max barrier.  At staleness_bound 0 with
@@ -431,9 +436,9 @@ class RoundDriver:
     axis of all fleet state — parameter replicas, batches, aggregation
     buffers — over the mesh's fleet axis (DESIGN.md §11): state is
     placed at init/load, the engines' donated steps keep it sharded in
-    place across rounds, aggregation reduces mesh-wide, and the
-    broadcast re-places device-to-device (the fault path's degraded /
-    orphan-repaired rounds re-place without host round-trips).  Only the
+    place across rounds, and aggregation reduces mesh-wide and writes
+    the next replicas back onto the placement device-to-device (a lost
+    round keeps the placed replicas as they are).  Only the
     stacked-replica algorithms support it (fedpairing on the vmapped /
     bucketed engines, fl); the sl/splitfed relays train single trees,
     and the dist engine owns its own one-client-per-device mesh, which
@@ -987,12 +992,6 @@ class RoundDriver:
         return jax.tree_util.tree_map(lambda a: jnp.array(a[0]),
                                       state.client_params)
 
-    def _agg_snapshot_from(self, g_prev: Dict) -> Optional[Dict]:
-        """Reuse a snapshot a caller already holds (the fault path's
-        rollback copy) — None for stateless policies, so ``_agg_ctx``
-        short-circuits identically to ``_agg_snapshot``."""
-        return g_prev if self.agg_policy.stateful else None
-
     def _agg_ctx(self, g_prev: Optional[Dict], partner, lengths,
                  eta: float) -> Optional[aggregation.AggContext]:
         """The round's AggContext for the stateful policies (None for
@@ -1020,143 +1019,82 @@ class RoundDriver:
             active=np.asarray(active, bool),
             staleness=self._staleness_arg(ac),
             state=state.agg, ctx=ctx, round_idx=state.round,
-            into_replicas=True, sharding=self.sharding)
+            sharding=self.sharding)
 
     def _fedpairing_round(self, state, fleet, cohort, active, pair_seed):
-        rc = self.rc
-        faulted = self.fault_model.enabled
+        """One FedPairing round (DESIGN.md §9).
+
+        Plan, realize the round's faults (``no_faults`` when nothing is
+        stochastic: no draw), degrade the plan around dropouts, and
+        evaluate the Eq. (3) clock with its deadline — before any
+        training, so the clock decides whether the round is lost.  A
+        completed round trains the executed plan and aggregates over the
+        survivors; a lost one (skipped / aborted) hands back the pre-round
+        replicas untouched.  The data stream advances
+        ``batches_per_round`` calls on every outcome (the resume
+        contract)."""
+        rc, fcfg = self.rc, self.fault_cfg
         with telemetry.span("plan"):
             plan, anchor, replanned = self._adaptive_plan(
                 state, fleet, cohort, active, pair_seed)
-            if not faulted:
-                partner = plan.partner_array()
-                agg_w = fedpair.pair_weights(fleet.data_sizes, partner)
-                g_prev = self._agg_snapshot(state)
-        if faulted:
-            return self._fedpairing_faulted(state, fleet, cohort, active,
-                                            plan, anchor, replanned)
-        params, losses = self._train(
-            lambda p, b: self._engine.step(p, b, plan, agg_w),
-            state.client_params)
-        mean_loss = _mean_active_loss(losses, active,
-                                      round_idx=state.round)
-        with telemetry.span("clock"):
-            units, times, upload_s = latency.round_clock_plan(
-                self._latency_plan(fleet, partner, active, plan), fleet,
-                self.chan, self.workload)
-            if rc.async_rounds:
-                clock, ac = self._advance_clock(state, cohort, units, times,
-                                                upload_s)
-                round_s, wait_s, overlap_s = (ac.round_s, ac.wait_s,
-                                              ac.overlap_s)
-            else:
-                clock, ac = state.clock, None
-                round_s = float(np.max(times)) + upload_s
-                wait_s, overlap_s = latency.barrier_wait_s(times), 0.0
-        with telemetry.span("aggregate"):
-            params, agg = self._aggregate(
-                state, params, fleet, active, ac, rc.aggregation,
-                self._agg_ctx(g_prev, partner, plan.lengths_array(),
-                              eta=rc.lr / self.n))
-        with telemetry.span("record"):
-            rec = self._record(state, cohort, plan.pairs, plan.lengths,
-                               mean_loss, round_s, self._engine.cached_steps,
-                               objective=plan.objective, replanned=replanned,
-                               cut_cache=self._cut_cache_status(replanned),
-                               wait_s=wait_s, overlap_s=overlap_s)
-            if rc.overlap_planning:
-                self._overlap_prebuild(fleet, active)
-        return rec, params, None, anchor, clock, agg
-
-    def _fedpairing_faulted(self, state, fleet, cohort, active, plan,
-                            anchor, replanned):
-        """The fedpairing round under fault injection (DESIGN.md §9).
-
-        Realize the round's faults (stateless per-round rng), apply the
-        degradation ladder (dropouts leave, orphans re-pair or go solo),
-        evaluate the faulted Eq. (3) clock with its deadline, train the
-        degraded plan, and aggregate over the survivors only — or skip /
-        abort the round cleanly with the pre-round global model restored
-        (``status`` records which).  The data stream always advances
-        ``batches_per_round`` calls, trained or not, so round k consumes
-        the same batches on every outcome (the resume contract).
-        """
-        rc = self.rc
-        fcfg = self.fault_cfg
-        with telemetry.span("plan"):
             rf = self.fault_model.realize(state.round, active, plan.pairs)
-            # pre-round global snapshot (row 0; all rows equal after the
-            # previous broadcast): with donate=True the engines consume the
-            # input buffers, but a skipped/aborted round must hand back the
-            # pre-round model untouched
-            g_prev = jax.tree_util.tree_map(lambda a: jnp.array(a[0]),
-                                            state.client_params)
             exec_plan, exec_active = plan, np.asarray(active, bool)
             if fcfg.mode == "graceful" and rf.dropped:
-                partner2, exec_active = faults.degrade_partner(
+                partner, exec_active = faults.degrade_partner(
                     plan.partner_array(), exec_active, rf, fcfg.orphan)
-                exec_plan = self.round_plan(fleet, partner2, exec_active)
+                exec_plan = self.round_plan(fleet, partner, exec_active)
             partner = exec_plan.partner_array()
+            agg_w = fedpair.pair_weights(fleet.data_sizes, partner)
+            g_prev = self._agg_snapshot(state)
         with telemetry.span("clock"):
             clock = faults.faulted_clock(
                 self._latency_plan(fleet, partner, exec_active, exec_plan),
                 fleet, self.chan, self.workload, rf, fcfg)
-        excluded = sorted(set(rf.dropped) | set(clock.late)
-                          | set(clock.link_failed))
-        final_active = exec_active.copy()
-        final_active[[c for c in excluded if c < self.n]] = False
-        event_clock, ac, agg = state.clock, None, state.agg
-        if not clock.completed:
-            # graceful with no survivor -> skipped; abort with any
-            # failure -> aborted.  Params roll back to the pre-round
-            # global; the batch stream still advances.
-            for _ in range(rc.batches_per_round):
-                self._next_batch()
-            with telemetry.span("broadcast"):
-                params = aggregation.broadcast(g_prev, self.n,
-                                               sharding=self.sharding)
-            status = "aborted" if fcfg.mode == "abort" else "skipped"
-            mean_loss = float("nan")
+            excluded = sorted(set(rf.dropped) | set(clock.late)
+                              | set(clock.link_failed))
+            event_clock, ac = state.clock, None
             round_s, wait_s, overlap_s = clock.round_s, 0.0, 0.0
-            if rc.async_rounds:
+            if rc.async_rounds and not clock.completed:
                 # a lost round is a barrier event: the faulted cost is
                 # global (the server waited out the deadline) and every
                 # client resyncs at the publish — nothing to pipeline
-                with telemetry.span("clock"):
-                    event_clock, _ = latency.advance_event_clock_barrier(
-                        event_clock, clock.round_s, rc.staleness_bound)
+                event_clock, _ = latency.advance_event_clock_barrier(
+                    event_clock, clock.round_s, rc.staleness_bound)
+            elif rc.async_rounds:
+                # replay the surviving units on the event clock, capped by
+                # the deadline the sync accounting obeys; excluded clients
+                # resync to the merge (they rejoin fresh)
+                event_clock, ac = self._advance_clock(
+                    state, cohort, clock.units, clock.times, clock.upload_s,
+                    cap_s=(clock.deadline_s
+                           if np.isfinite(clock.deadline_s) else None),
+                    resync=excluded)
+                round_s, wait_s, overlap_s = (ac.round_s, ac.wait_s,
+                                              ac.overlap_s)
+            elif clock.completed:
+                wait_s = latency.barrier_wait_s(clock.times)
+        if not clock.completed:
+            for _ in range(rc.batches_per_round):
+                self._next_batch()
+            params, agg, mean_loss = (state.client_params, state.agg,
+                                      float("nan"))
+            status = "aborted" if fcfg.mode == "abort" else "skipped"
         else:
-            agg_w = fedpair.pair_weights(fleet.data_sizes, partner)
+            final_active = exec_active.copy()
+            final_active[excluded] = False
             params, losses = self._train(
                 lambda p, b: self._engine.step(p, b, exec_plan, agg_w),
                 state.client_params)
             mean_loss = _mean_active_loss(losses, final_active,
                                           round_idx=state.round)
-            if rc.async_rounds:
-                # replay the realized surviving units on the event clock,
-                # capped by the same deadline the sync accounting obeys;
-                # excluded clients resync to the merge (they rejoin fresh)
-                with telemetry.span("clock"):
-                    event_clock, ac = self._advance_clock(
-                        state, cohort, clock.units,
-                        np.asarray(clock.times, np.float64), clock.upload_s,
-                        cap_s=(clock.deadline_s
-                               if np.isfinite(clock.deadline_s) else None),
-                        resync=[c for c in excluded if c < self.n])
-                round_s, wait_s, overlap_s = (ac.round_s, ac.wait_s,
-                                              ac.overlap_s)
-            else:
-                round_s = clock.round_s
-                wait_s, overlap_s = latency.barrier_wait_s(clock.times), 0.0
             # variate attribution follows the DEGRADED plan and the
             # post-fault survivor mask: an excluded client's variate
             # stays put and never moves c_global (the hard-mask contract)
             with telemetry.span("aggregate"):
                 params, agg = self._aggregate(
                     state, params, fleet, final_active, ac, rc.aggregation,
-                    self._agg_ctx(
-                        self._agg_snapshot_from(g_prev), partner,
-                        exec_plan.lengths_array(), eta=rc.lr / self.n))
+                    self._agg_ctx(g_prev, partner, exec_plan.lengths_array(),
+                                  eta=rc.lr / self.n))
             status = "degraded" if excluded else "ok"
         with telemetry.span("record"):
             rec = self._record(state, cohort, exec_plan.pairs,

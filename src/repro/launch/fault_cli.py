@@ -70,9 +70,8 @@ def add_checkpoint_args(ap: argparse.ArgumentParser) -> None:
 
 def fault_config(args: argparse.Namespace
                  ) -> Optional[faults.FaultConfig]:
-    """A FaultConfig from parsed flags — or None when every fault flag is
-    at its zero default, so the driver keeps the historical fault-free
-    path bit-identically."""
+    """A FaultConfig from parsed flags — or None (``FaultConfig()``) when
+    every fault flag is at its zero default."""
     if (args.fault_dropout == 0.0 and args.fault_straggler == 0.0
             and args.fault_outage == 0.0 and args.fault_deadline == 0.0):
         return None

@@ -244,12 +244,12 @@ class TestAggregationInvariants:
                      if stale else None)
         pol = aggregation.ScaffoldAggregation()
         state = pol.init_state(_row0(tree), n)
-        g_s, new_state = pol.apply(tree, agg_w, mode, staleness=staleness,
-                                   state=state,
-                                   ctx=_pair_ctx(rng, tree, n))
         g_m = aggregation.aggregate(tree, agg_w, mode,
                                     staleness=staleness)
-        assert _tree_equal(g_s, g_m)
+        replicas, new_state = pol.apply(tree, agg_w, mode,
+                                        staleness=staleness, state=state,
+                                        ctx=_pair_ctx(rng, tree, n))
+        assert _tree_equal(_row0(replicas), g_m)
         assert new_state.applied     # correction arms for the NEXT round
 
     @settings(max_examples=12, deadline=None)
@@ -310,7 +310,7 @@ class TestAggregationInvariants:
             for leaf in jax.tree_util.tree_leaves(st2.c_global):
                 assert bool(jnp.isfinite(leaf).all())
             incl = np.flatnonzero(active)
-            outs.append((jax.tree_util.tree_map(lambda a: a, g),
+            outs.append((_row0(g),
                          st2.c_global,
                          jax.tree_util.tree_map(lambda a: a[incl],
                                                 st2.c_local)))

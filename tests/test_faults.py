@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import telemetry
 from repro.configs import get_smoke_config
 from repro.core import aggregation, faults, latency, planning, rounds
 from repro.hypothesis_compat import given, settings, strategies as st
@@ -107,6 +108,44 @@ class TestZeroCost:
         sz = _driver(faults=faults.FaultConfig(seed=3)).run()
         assert s0.history == sz.history
         _tree_equal(s0.client_params, sz.client_params)
+
+    @pytest.mark.parametrize("clock", [
+        {}, dict(async_rounds=True, staleness_bound=1)],
+        ids=["sync", "async"])
+    @pytest.mark.parametrize("engine", ["vmapped", "bucketed"])
+    def test_enabled_layer_realizing_nothing_is_fault_free(self, engine,
+                                                           clock):
+        """An ENABLED fault layer whose deadline nobody misses realizes
+        no fault, and its trace and parameters equal ``faults=None``."""
+        fc = faults.FaultConfig(deadline_factor=1e6)
+        assert fc.enabled and not fc.randomized
+        s0 = _driver(engine, **clock).run()
+        sf = _driver(engine, faults=fc, **clock).run()
+        assert s0.history == sf.history
+        assert all(r.status == "ok" for r in sf.history)
+        _tree_equal(s0.client_params, sf.client_params)
+
+    @pytest.mark.parametrize("n", [2, 4, 7, 16])
+    def test_clock_without_faults_is_the_fault_free_clock(self, n):
+        """With no realized fault, ``faulted_clock`` gives the units,
+        times, upload and round time of ``latency.round_clock_plan``
+        exactly (odd n leaves one client solo)."""
+        fleet = latency.make_fleet(n=n, seed=n)
+        partner = np.arange(n)
+        for i in range(0, n - 1, 2):
+            partner[i], partner[i + 1] = i + 1, i
+        plan = planning.build_round_plan(fleet, CHAN, partner, W,
+                                         workload=WORK)
+        units, times, upload_s = latency.round_clock_plan(plan, fleet, CHAN,
+                                                          WORK)
+        c = faults.faulted_clock(plan, fleet, CHAN, WORK,
+                                 faults.no_faults(n), faults.FaultConfig())
+        assert c.completed
+        assert (c.late, c.link_failed) == ((), ())
+        assert c.units == units
+        assert c.times == tuple(times)
+        assert c.upload_s == upload_s
+        assert c.round_s == float(np.max(times)) + upload_s
 
     def test_fail_prob_none_when_rates_zero(self):
         m = faults.FaultModel(faults.FaultConfig(deadline_factor=2.0), N)
@@ -226,6 +265,32 @@ class TestDegradation:
         assert np.isnan(rec.mean_loss)
         assert rec.failed == tuple(range(N))
         _tree_equal(g0, d.global_params(st))
+
+    @pytest.mark.parametrize("mode,status", [("graceful", "skipped"),
+                                             ("abort", "aborted")])
+    def test_lost_round_hands_back_the_same_buffers(self, mode, status):
+        """A lost round trains nothing, so its next replicas are the very
+        buffers it was given: no broadcast, no aggregation program."""
+        fc = _fc(dropout=(0.95,) * N, outage=0.0, straggler=0.0,
+                 deadline_factor=0.0, mode=mode)
+        d = _driver(faults=fc, donate=True)
+        st = d.init_state()
+        before = jax.tree_util.tree_leaves(st.client_params)
+        telemetry.reset()
+        telemetry.enable()
+        try:
+            st = d.run_round(st)
+            names = {e.name for e in telemetry.events()}
+        finally:
+            telemetry.disable()
+            telemetry.reset()
+        assert st.history[-1].status == status
+        after = jax.tree_util.tree_leaves(st.client_params)
+        assert len(after) == len(before)
+        assert all(a is b for a, b in zip(after, before))
+        assert not any(a.is_deleted() for a in after)
+        assert "broadcast" not in names and "agg_program" not in names
+        assert {"round", "plan", "clock", "batch", "record"} <= names
 
     def test_abort_round_keeps_params_and_pays_clock(self):
         graceful = _driver(faults=_fc()).run()
